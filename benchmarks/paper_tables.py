@@ -251,4 +251,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

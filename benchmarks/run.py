@@ -21,6 +21,9 @@ def main() -> None:
 
     # ---- paper tables/figures (analytical CUTIE model) ----
     from benchmarks import paper_tables as pt
+    from repro.launch.compile_cache import use_compile_cache  # src/ is on the path now
+
+    use_compile_cache()
 
     for name, model_v, paper_v in pt.table1():
         d = f"model={model_v:.4g}" + ("" if paper_v is None else f";paper={paper_v:.4g}")

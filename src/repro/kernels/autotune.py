@@ -13,15 +13,17 @@ Selection rule (`block_for_layer`):
     tile width: one grid cell per OCU tile pass, so kernel launches and
     priced tile passes line up one-to-one.  For the paper nets this yields
     96 — the OCU count — on every 96-channel layer.
-  * **measured fallback** — when the plan cannot describe the layer as
-    uniform single-window passes (a 5x5 stem needs multiple window passes
-    per tile; ragged C_out yields mixed tile widths), the block comes from
-    `MEASURED_FALLBACK_BLOCKS`, a table measured on this container's
-    interpreter/native path where fewer, larger launches always won: the
-    largest measured block that divides C_out exactly, else one single
-    C_out-wide block (ops.py never has to pad).  `cifar10_tnn_wide`'s 5x5
-    stem — the net `sim.reconcile` reports ``analytic_schedulable=False``
-    for — is the designed counterexample exercising this path.
+  * **fallback** — when the plan cannot describe the layer as uniform
+    single-window passes (a 5x5 stem needs multiple window passes per
+    tile; ragged C_out yields mixed tile widths), or when the tile width is
+    a block the TPU cannot tile, the block is 128 where 128 divides C_out
+    and one single C_out-wide block otherwise (ops.py never has to pad).
+    `cifar10_tnn_wide` — 192 channels in 96-wide tile passes, and the 5x5
+    stem `sim.reconcile` reports ``analytic_schedulable=False`` for — is
+    the designed counterexample exercising this path.
+
+Every block is one Pallas TPU accepts: a last block dimension must be a
+multiple of the 128-lane tile or the whole array dimension (`tpu_legal`).
 
 Everything here is a pure function of the plan: same plan, same blocks —
 determinism is pinned in tests/test_autotune.py.
@@ -34,10 +36,9 @@ from typing import TYPE_CHECKING, Dict, List
 if TYPE_CHECKING:  # avoid a hard kernels -> sim import at module load
     from repro.sim.plan import ExecutionPlan, LayerPlan
 
-# Block candidates, measured (benchmarks/kernel_bench.py lineage) largest
-# first: on both the native path and the Pallas interpreter, grid-cell /
-# launch count dominates at these sizes, so the largest dividing block wins.
-MEASURED_FALLBACK_BLOCKS = (128, 96, 64, 48, 32, 24, 16, 8)
+# The TPU vector lane count: a Pallas block's last dimension must be a
+# multiple of it, or span the whole array dimension.
+LANES = 128
 
 # The OCU window engine holds kh x kw <= 3 x 3 natively; anything larger
 # takes multiple window passes per tile and leaves the plan-derived regime.
@@ -48,19 +49,24 @@ _NATIVE_WINDOW = 3
 class KernelBlock:
     """One layer's autotuned kernel block.  ``source`` records provenance:
     ``"plan"`` (the `TileAssign` cout width, launches == tile passes) or
-    ``"fallback"`` (the measured table — the plan can't schedule the layer
-    as uniform single-window passes)."""
+    ``"fallback"`` (the plan can't schedule the layer as uniform
+    single-window passes, or its tile width is no TPU block)."""
 
     block_cout: int
     source: str  # "plan" | "fallback"
 
 
+def tpu_legal(block_cout: int, c_out: int) -> bool:
+    """True when Pallas on TPU can tile ``c_out`` channels in blocks of
+    ``block_cout`` without padding: the whole width, or a dividing
+    multiple of the lane count."""
+    return block_cout == c_out or (block_cout % LANES == 0 and c_out % block_cout == 0)
+
+
 def _fallback_block(c_out: int) -> int:
-    for b in MEASURED_FALLBACK_BLOCKS:
-        if b <= c_out and c_out % b == 0:
-            return b
-    # nothing measured divides: one ragged-width block, ops.py pads nothing
-    return c_out
+    # a lane-wide block where it divides, else one C_out-wide block (ops.py
+    # pads nothing either way)
+    return LANES if c_out % LANES == 0 else c_out
 
 
 def block_for_layer(lp: "LayerPlan") -> KernelBlock:
@@ -72,7 +78,8 @@ def block_for_layer(lp: "LayerPlan") -> KernelBlock:
             "conv2d/tcn layers dispatch through ternary_conv2d"
         )
     widths = lp.cout_tile_widths
-    if len(widths) == 1 and lp.kh <= _NATIVE_WINDOW and lp.kw <= _NATIVE_WINDOW:
+    if (len(widths) == 1 and lp.kh <= _NATIVE_WINDOW and lp.kw <= _NATIVE_WINDOW
+            and tpu_legal(widths[0], lp.c_out)):
         return KernelBlock(block_cout=widths[0], source="plan")
     return KernelBlock(block_cout=_fallback_block(lp.c_out), source="fallback")
 

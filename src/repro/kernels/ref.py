@@ -1,7 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These define the semantics; kernels must match them allclose (bit-exact for
-ternary integer data).  Tests sweep shapes/dtypes against these.
+ternary integer data).  Tests sweep shapes/dtypes against these.  Every dot
+runs at ``HIGHEST`` precision: on TPU the default rounds f32 operands to
+bf16, which would make the oracle itself inexact on real-valued inputs.
 """
 from __future__ import annotations
 
@@ -14,7 +16,8 @@ from repro.core.ternary import unpack_ternary
 def ternary_matmul_ref(x: jax.Array, w_packed: jax.Array, scale: jax.Array) -> jax.Array:
     """y = x @ unpack(w_packed) * scale   (scale broadcast over N)."""
     w = unpack_ternary(w_packed, axis=0).astype(jnp.float32)
-    y = jnp.dot(x.astype(jnp.float32), w) * scale.reshape(1, -1).astype(jnp.float32)
+    y = jnp.dot(x.astype(jnp.float32), w, precision=jax.lax.Precision.HIGHEST)
+    y = y * scale.reshape(1, -1).astype(jnp.float32)
     return y.astype(x.dtype)
 
 
@@ -40,6 +43,7 @@ def ternary_conv2d_ref(
         window_strides=(1, 1),
         padding="SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST,
     ) * scale.reshape(1, 1, 1, -1).astype(jnp.float32)
     if fuse_ternary:
         y = jnp.where(jnp.abs(y) > jnp.asarray(threshold, jnp.float32), jnp.sign(y), 0.0)

@@ -28,7 +28,8 @@ inter-layer traffic is exactly the silicon's 2-bit activation memory model.
 
 Two implementations share the decode + tap walk + epilogue semantics:
 
-  * ``ternary_conv2d_pallas`` — the Pallas kernel (TPU; interpreter on CPU).
+  * ``ternary_conv2d_pallas`` — the Pallas kernel, compiled for TPU
+    (``interpret=True`` runs the Pallas interpreter on any host).
   * ``ternary_conv2d_native`` — the SAME per-tap matmuls lowered as straight
     XLA ops, batched over samples.  On CPU hosts this skips the Pallas
     interpreter's per-grid-cell emulation entirely; `ops.ternary_conv2d`
@@ -49,24 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_SHIFTS = (0, 2, 4, 6)
-
-
-def _select_w(wp: jax.Array, dtype) -> jax.Array:
-    """[KH, KW, C4, bn] uint8 -> [KH, KW, 4*C4, bn] add/subtract-select
-    operands in ``dtype``: per 2-bit code, ``plus = b1``, ``minus =
-    NOR(b1, b0)``, operand = plus - minus in {-1, 0, +1}
-    (`core.ternary.select_masks`, inlined in unrolled-shift form so the
-    Pallas kernel body needs no axis moves)."""
-    kh, kw, c4, bn = wp.shape
-    parts = []
-    for s in _SHIFTS:
-        code = (wp >> s) & jnp.uint8(3)
-        plus = (code >> 1) & jnp.uint8(1)
-        minus = ((code | (code >> 1)) & jnp.uint8(1)) ^ jnp.uint8(1)
-        parts.append(plus.astype(jnp.int8) - minus.astype(jnp.int8))
-    w = jnp.stack(parts, axis=3)  # (kh, kw, c4, 4, bn)
-    return w.reshape(kh, kw, c4 * 4, bn).astype(dtype)
+from repro.kernels.ternary_matmul import select_tile
 
 
 def _epilogue(y, scale, thr, *, h: int, w: int, bn: int,
@@ -95,15 +79,19 @@ def _tconv_kernel(
     """One (sample, output-channel-tile) grid cell: full-image conv."""
     c_in = x_ref.shape[-1]
     bn = o_ref.shape[-1]
-    wt = _select_w(wp_ref[...], jnp.float32)
+    # [KH, KW, C4, bn] uint8 -> [KH, KW, C_in, bn]: the packed C_in axis is
+    # axis -2, exactly the matmul kernel's K axis
+    wt = select_tile(wp_ref[...], jnp.float32)
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     # 9 shifted matmuls == the line-buffer window walk, output-stationary.
+    # The window is widened to f32 before the (h, w, c) -> (h*w, c) merge:
+    # Mosaic cannot merge int8 rows at every width (TCN D=2 has w=2).
     for dy in range(kh):
         for dx in range(kw):
-            xs = x_ref[0, dy : dy + h, dx : dx + w, :].reshape(h * w, c_in)
+            xs = x_ref[0, dy : dy + h, dx : dx + w, :].astype(jnp.float32)
             acc_ref[...] += jax.lax.dot_general(
-                xs.astype(jnp.float32),
+                xs.reshape(h * w, c_in),
                 wt[dy, dx],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -144,7 +132,7 @@ def ternary_conv2d_pallas(
     block_cout: int = 128,
     fuse_ternary: bool = False,
     fuse_pool: int = 0,
-    interpret: bool = True,
+    interpret: bool = False,
     out_dtype=None,
 ):
     """SAME ternary conv.  x: [B, H, W, C_in] (unpadded), w_packed:
@@ -155,7 +143,8 @@ def ternary_conv2d_pallas(
     ragged C_out up to the block; a direct caller with a non-dividing block
     gets a `ValueError`, not a silent bad grid.  ``fuse_pool`` > 1 appends
     a window/stride ``fuse_pool`` max-pool to the epilogue (after the
-    optional ternarization), shrinking the output to [B, H/p, W/p, C_out]."""
+    optional ternarization), shrinking the output to [B, H/p, W/p, C_out].
+    The kernel compiles for TPU; only ``interpret=True`` runs it elsewhere."""
     b, h, w, c_in = x.shape
     kh, kw, c4, c_out = w_packed.shape
     _check_geometry(c_in, c4, h, w, fuse_pool)
@@ -219,7 +208,7 @@ def ternary_conv2d_native(
     out_dtype = out_dtype or x.dtype
     ph, pw = kh // 2, kw // 2
     xp = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw), (0, 0)))
-    wt = _select_w(w_packed, jnp.float32)
+    wt = select_tile(w_packed, jnp.float32)
 
     acc = jnp.zeros((b * h * w, c_out), jnp.float32)
     for dy in range(kh):

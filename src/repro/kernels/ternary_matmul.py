@@ -41,22 +41,26 @@ from jax.experimental.pallas import tpu as pltpu
 _SHIFTS = (0, 2, 4, 6)
 
 
-def _select_tile(wp: jax.Array, dtype) -> jax.Array:
-    """(bk/4, bn) uint8 -> (bk, bn) add/subtract-select operands in
+def select_tile(wp: jax.Array, dtype) -> jax.Array:
+    """[..., K/4, N] uint8 -> [..., K, N] add/subtract-select operands in
     ``dtype``, values {-1, 0, +1} via the plus/minus single-bit selects.
 
     The expansion is sublane-structured: byte row r expands to rows
-    4r..4r+3, matching pack_ternary(axis=0 of the K dimension).
+    4r..4r+3, matching pack_ternary along the K axis (axis -2).  The bytes
+    are widened to int32 before any bit operation: Mosaic on TPU cannot
+    shift 8-bit lanes, nor interleave 8-bit rows.  Shared by both kernels'
+    bodies and their native XLA twins, so every impl decodes identically.
     """
-    bk4, bn = wp.shape
+    wp = wp.astype(jnp.int32)
+    *lead, k4, n = wp.shape
     parts = []
     for s in _SHIFTS:
-        code = (wp >> s) & jnp.uint8(3)
-        plus = (code >> 1) & jnp.uint8(1)
-        minus = ((code | (code >> 1)) & jnp.uint8(1)) ^ jnp.uint8(1)
-        parts.append(plus.astype(jnp.int8) - minus.astype(jnp.int8))
-    w = jnp.stack(parts, axis=1)  # (bk4, 4, bn)
-    return w.reshape(bk4 * 4, bn).astype(dtype)
+        code = (wp >> s) & 3
+        plus = code >> 1
+        minus = ((code | plus) & 1) ^ 1
+        parts.append(plus - minus)
+    w = jnp.stack(parts, axis=-2)  # (..., k4, 4, n)
+    return w.reshape(*lead, k4 * 4, n).astype(dtype)
 
 
 def _tmm_kernel(x_ref, wp_ref, scale_ref, o_ref, acc_ref, *, n_k: int):
@@ -67,7 +71,7 @@ def _tmm_kernel(x_ref, wp_ref, scale_ref, o_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]
-    w = _select_tile(wp_ref[...], x.dtype)
+    w = select_tile(wp_ref[...], x.dtype)
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -89,7 +93,7 @@ def ternary_matmul_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
     out_dtype=None,
 ):
     """y[M, N] = x[M, K] @ select_decode(w_packed)[K, N] * scale[N].
@@ -97,7 +101,8 @@ def ternary_matmul_pallas(
     ``w_packed``: [K/4, N] uint8 (pack_ternary along K).  ``scale``: [N] or
     [1, N] per-output-channel alpha.  M, K, N must already be padded to the
     block sizes (ops.py handles padding); a direct caller with non-dividing
-    blocks gets a `ValueError`, not a silent bad grid.
+    blocks gets a `ValueError`, not a silent bad grid.  The kernel
+    compiles for TPU; only ``interpret=True`` runs it elsewhere.
     """
     m, k = x.shape
     k4, n = w_packed.shape
@@ -153,7 +158,7 @@ def ternary_matmul_native(
             f"K={k} does not match packed K/4={k4}: pad x to the 4-trit "
             "pack quantum (kernels.ops.ternary_matmul pads)"
         )
-    w = _select_tile(w_packed, x.dtype)
+    w = select_tile(w_packed, x.dtype)
     y = jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )

@@ -553,5 +553,8 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     rc = main()
     sys.exit(rc if isinstance(rc, int) else 0)

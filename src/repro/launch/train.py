@@ -132,4 +132,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -41,8 +41,9 @@ means many tenants running *different* registry nets concurrently — the
     burst (`repro.serving.gating`; CI ``gate-smoke``).
   * **Device sharding.**  ``sharding="auto"`` lays every bucket's pool
     axis across all local devices (per-pool `NamedSharding`, a no-op on
-    single-device hosts) — ladder sizes divisible by the device count
-    shard; others run replicated.
+    single-device hosts).  On a multi-device host every ladder rung must
+    divide across the devices: registration fails otherwise, rather than
+    running some rungs on one device.
 
 Entry points::
 
@@ -70,7 +71,7 @@ import numpy as np
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.tracer import NULL_TRACER
 from repro.serving.gating import ActivityGate
-from repro.serving.pool import SessionPool
+from repro.serving.pool import SessionPool, resolve_sharding
 from repro.serving.scheduler import ContinuousBatcher, StreamRequest, StreamResult
 
 DEFAULT_MAX_POOL = 16
@@ -266,6 +267,8 @@ class NetBucket:
             raise ValueError(f"ladder must be ascending positive sizes, got {ladder}")
         if queue_limit < 1 or shrink_after < 1:
             raise ValueError("queue_limit and shrink_after must be >= 1")
+        for size in ladder:  # refuse an unshardable rung now, not mid-run
+            resolve_sharding(sharding, size)
         self.name = name
         self.program = program
         self.backend = backend

@@ -24,8 +24,9 @@ Key properties (all tested in tests/test_serving.py):
     pytree; `admit(sid, state=...)` scatters it back in — into this pool,
     another pool, or a standalone `StreamSession`.
   * **Optional batch-axis sharding.**  `sharding="auto"` lays the pool axis
-    across local devices via `jax.sharding.NamedSharding` when the pool
-    size divides the device count evenly (single-device hosts: no-op).
+    across local devices via `jax.sharding.NamedSharding` (single-device
+    hosts: no-op; a pool size the device count does not divide is an
+    error, never a silent single-device run).
 
 Empty slots still compute (a zero frame through the CNN) — exactly like the
 silicon, which clocks every OCU whether or not the pixel is useful; the
@@ -66,12 +67,13 @@ class PoolFullError(RuntimeError):
     `repro.serving.scheduler.ContinuousBatcher`)."""
 
 
-def _resolve_sharding(
+def resolve_sharding(
     sharding: Union[str, bool, int, None, jax.sharding.Sharding], pool_size: int
 ) -> Optional[jax.sharding.Sharding]:
     """Turn the user-facing `sharding` argument into a concrete Sharding (or
-    None).  "auto"/True shard over all local devices when that divides the
-    pool evenly; an int requests exactly that many devices (hard error when
+    None).  "auto"/True shard over all local devices (a no-op on a
+    single-device host, a hard error when the pool does not divide across
+    several); an int requests exactly that many devices (hard error when
     impossible); a Sharding passes through."""
     if sharding is None or sharding is False:
         return None
@@ -80,8 +82,14 @@ def _resolve_sharding(
     devices = jax.local_devices()
     if sharding == "auto" or sharding is True:
         n = len(devices)
-        if n <= 1 or pool_size % n:
+        if n <= 1:
             return None
+        if pool_size % n:
+            raise ValueError(
+                f'sharding="auto": pool_size {pool_size} does not divide '
+                f"across this host's {n} devices; use a multiple of {n}, an "
+                "int device count, or sharding=None for one device"
+            )
     elif isinstance(sharding, int):
         n = sharding
         if n > len(devices):
@@ -134,7 +142,7 @@ class SessionPool:
         # call from the OUTSIDE — nothing observes inside the trace
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.track = getattr(deployed.graph, "name", "pool")
-        self.sharding = _resolve_sharding(sharding, pool_size)
+        self.sharding = resolve_sharding(sharding, pool_size)
         if self.sharding is not None:
             self.state = self._put(self.state)
 
@@ -145,7 +153,18 @@ class SessionPool:
             logits = deployed.temporal_forward(ordered_windows(new), backend)
             return logits, new
 
-        self._step = jax.jit(_step) if jit else _step
+        step = _step
+        if isinstance(self.sharding, jax.sharding.NamedSharding):
+            # every slot is independent, so each device runs the whole step
+            # on its own slice of the pool; a Pallas kernel cannot be
+            # partitioned by the compiler, only mapped like this (and its
+            # outputs carry no varying-axis annotation, hence check_vma off)
+            spec = self.sharding.spec
+            step = jax.shard_map(
+                _step, mesh=self.sharding.mesh, in_specs=(spec, spec, spec),
+                out_specs=(spec, spec), check_vma=False,
+            )
+        self._step = jax.jit(step) if jit else step
 
     # -- sharding helper ---------------------------------------------------
 

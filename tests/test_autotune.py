@@ -13,10 +13,10 @@ import pytest
 
 from repro import api
 from repro.kernels.autotune import (
-    MEASURED_FALLBACK_BLOCKS,
     KernelBlock,
     block_for_layer,
     kernel_block_plan,
+    tpu_legal,
 )
 from repro.sim.plan import lower
 
@@ -36,7 +36,8 @@ def _deploy(name, batch=2, seed=0):
 
 class TestSelectionRule:
     def test_uniform_small_window_layers_are_plan_derived(self):
-        """Every <=3x3 conv/tcn layer with one tile width gets that width."""
+        """Every <=3x3 conv/tcn layer with one TPU-legal tile width gets
+        that width; every block, plan or fallback, is one the TPU tiles."""
         for name in api.list_nets():
             plan = lower(api.get_graph(name))
             for lp in plan.layers:
@@ -44,31 +45,46 @@ class TestSelectionRule:
                     continue
                 kb = block_for_layer(lp)
                 widths = lp.cout_tile_widths
-                if len(widths) == 1 and lp.kh <= 3 and lp.kw <= 3:
+                if (len(widths) == 1 and lp.kh <= 3 and lp.kw <= 3
+                        and tpu_legal(widths[0], lp.c_out)):
                     assert kb == KernelBlock(widths[0], "plan"), (name, lp.index)
                 else:
                     assert kb.source == "fallback", (name, lp.index)
                 assert lp.c_out % kb.block_cout == 0, (name, lp.index)
+                assert tpu_legal(kb.block_cout, lp.c_out), (name, lp.index)
 
     def test_wide_stem_uses_fallback(self):
         """cifar10_tnn_wide's 5x5 stem — the analytic_schedulable=False net —
-        leaves the plan-derived regime; the fallback must still divide."""
+        leaves the plan-derived regime; the fallback must still divide and
+        be a block the TPU accepts (192 = one whole-width block)."""
         plan = lower(api.get_graph("cifar10_tnn_wide"))
         stem = next(lp for lp in plan.layers if lp.kind == "conv2d")
         assert (stem.kh, stem.kw) == (5, 5)
         kb = block_for_layer(stem)
         assert kb.source == "fallback"
-        assert kb.block_cout in MEASURED_FALLBACK_BLOCKS
-        assert stem.c_out % kb.block_cout == 0
+        assert kb.block_cout == stem.c_out == 192
+        assert tpu_legal(kb.block_cout, stem.c_out)
 
     def test_fallback_prefers_largest_dividing_block(self):
         from repro.kernels.autotune import _fallback_block
 
-        assert _fallback_block(192) == 96
+        assert _fallback_block(192) == 192  # 96 would not tile on TPU
+        assert _fallback_block(256) == 128
         assert _fallback_block(96) == 96
         assert _fallback_block(8) == 8
-        # nothing measured divides -> one ragged block, no padding in ops
+        # no lane multiple divides -> one ragged block, no padding in ops
         assert _fallback_block(10) == 10
+
+    def test_wide_net_96_tiles_are_not_tpu_blocks(self):
+        """cifar10_tnn_wide's 3x3 layers tile 192 channels in two 96-wide
+        OCU passes; 96 does not tile a 192-wide array on TPU, so they fall
+        back to one whole-width block."""
+        plan = lower(api.get_graph("cifar10_tnn_wide"))
+        convs = [lp for lp in plan.layers if lp.kind == "conv2d"][1:]
+        assert convs
+        for lp in convs:
+            assert lp.cout_tile_widths == (96,)
+            assert block_for_layer(lp) == KernelBlock(192, "fallback")
 
     def test_non_conv_layer_raises(self):
         plan = lower(api.get_graph("cifar10_tnn_smoke"))

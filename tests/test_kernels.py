@@ -28,6 +28,19 @@ class TestSelectDecode:
             np.asarray(unpack_ternary(p, axis=axis)),
         )
 
+    @pytest.mark.parametrize("shape", [(24, 96), (3, 3, 8, 40)])
+    def test_kernel_decode_matches_unpack(self, shape):
+        """The decode inside both kernel bodies (int32-widened bit algebra,
+        row interleave along the packed axis -2) equals unpack_ternary."""
+        from repro.kernels.ternary_matmul import select_tile
+
+        rng = np.random.RandomState(13)
+        t = jnp.asarray(rng.randint(-1, 2, shape).astype(np.int8))
+        p = pack_ternary(t, axis=-2)
+        got = select_tile(p, jnp.float32)
+        assert got.dtype == jnp.float32 and got.shape == t.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(t, np.float32))
+
     def test_masks_one_hot_per_trit(self):
         """plus/minus select lines are never both asserted (the OCU either
         adds, subtracts, or skips) and reproduce the trit as plus - minus."""

@@ -521,3 +521,69 @@ def test_pool_sharding_bit_exact_on_forced_devices():
     )
     assert proc.returncode == 0, proc.stderr
     assert "SHARDED-OK" in proc.stdout
+
+
+_SHARD_RULES_SCRIPT = """
+import jax, numpy as np
+assert len(jax.local_devices()) == 4, jax.local_devices()
+from repro.api.program import CutieProgram
+from repro.serving import FleetRouter, SessionPool
+from tests.test_serving import tiny_graph, clips_for
+
+prog = CutieProgram(tiny_graph())
+frames = clips_for(prog.graph, 8, 3, seed=13)
+dep = prog.quantize(prog.init(jax.random.PRNGKey(0)), calib=frames)
+try:
+    SessionPool(dep, 6, backend="ref", sharding="auto")
+except ValueError as e:
+    assert "does not divide" in str(e), e
+else:
+    raise SystemExit("auto sharding ran a 6-slot pool on 4 devices")
+try:
+    FleetRouter(backend="ref", ladder=(1, 2, 4), sharding="auto").register("net", dep)
+except ValueError as e:
+    assert "does not divide" in str(e), e
+else:
+    raise SystemExit("fleet accepted ladder rungs that cannot shard")
+FleetRouter(backend="ref", ladder=(4, 8), sharding="auto").register("net", dep)
+sharded = SessionPool(dep, 8, backend="fused", sharding="auto")
+plain = SessionPool(dep, 8, backend="fused")
+assert len(sharded.state.buf.sharding.device_set) == 4
+for i in range(8):
+    sharded.admit(f"s{i}"); plain.admit(f"s{i}")
+for t in range(3):
+    fr = {f"s{i}": frames[i, t] for i in range(8)}
+    a, b = sharded.step(fr), plain.step(fr)
+    for k in fr:
+        np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+assert sharded.trace_count == 1
+print("SHARD-RULES-OK")
+"""
+
+
+def test_auto_sharding_refuses_uneven_pools_on_forced_devices():
+    """On a multi-device host ``sharding="auto"`` never silently runs a pool
+    on one device: an uneven pool (or fleet ladder rung) is an error, and an
+    even fused pool maps its step over the devices bit-exactly."""
+    repo = Path(__file__).resolve().parents[1]
+    env = {
+        "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+        "JAX_PLATFORMS": "cpu",
+        "PYTHONPATH": f"{repo / 'src'}:{repo}",
+        "PATH": "/usr/bin:/bin:/usr/local/bin",
+    }
+    proc = subprocess.run(
+        [sys.executable, "-c", _SHARD_RULES_SCRIPT], env=env,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr
+    assert "SHARD-RULES-OK" in proc.stdout
+
+
+def test_auto_sharding_is_a_no_op_on_one_device():
+    if len(jax.local_devices()) != 1:
+        pytest.skip("needs a single-device host")
+    from repro.serving.pool import resolve_sharding
+
+    assert resolve_sharding("auto", 3) is None
+    assert resolve_sharding(True, 8) is None
