@@ -27,7 +27,8 @@ from repro.obs.tracer import Event, Tracer
 # Tick-phase taxonomy: the spans a `ContinuousBatcher.tick` decomposes
 # into, in emission order.  `phase_breakdown` reports each as a fraction
 # of total tick time (serving_bench schema-4 cell, ci_summary table).
-TICK_PHASES = ("gate.park", "gate.scan", "admit", "assemble", "step")
+TICK_PHASES = ("gate.park", "gate.scan", "admit", "assemble", "step",
+               "demux", "retire")
 
 SERVING_PID = 1
 SIM_PID = 100
@@ -44,7 +45,9 @@ def to_chrome(tracer: Tracer, meta: Optional[dict] = None) -> dict:
     Track layout: events carrying ``track=...`` land on a named lane (one
     per fleet bucket / net), everything else on a lane named after its
     emitting thread — so the feeder thread is visibly parallel to the
-    scheduler's tick spans.  Counter events always attach per-process."""
+    scheduler's tick spans.  Counter events always attach per-process.
+    On the wall clock ``otherData["epoch_ns"]`` anchors ts 0 to
+    `time.time_ns`, the clock of a `jax.profiler` session's start."""
     clock = tracer.clock
     thread_names = tracer.thread_names
     # lane name -> chrome tid (stable, in order of first appearance)
@@ -89,6 +92,9 @@ def to_chrome(tracer: Tracer, meta: Optional[dict] = None) -> dict:
                        "args": {"sort_index": tid}})
 
     other = {"clock": clock, "dropped_events": tracer.dropped}
+    if clock == "wall":
+        # wall-clock ns at ts 0: a span's wall time is epoch_ns + ts * 1000
+        other["epoch_ns"] = tracer.epoch_ns
     if meta:
         other.update(meta)
     return {"traceEvents": header + events, "displayTimeUnit": "ms",

@@ -13,7 +13,7 @@ constant memory):
         with tracer.span("assemble", track="dvs_a"):
             ...
     tracer.instant("wake", track="dvs_a", stream="cam-0")
-    tracer.counter("occupancy", 0.75, track="dvs_a")
+    tracer.counter("stalls", {"bank": 3, "ndb": 1}, track="dvs_a")
 
 Three event phases (Chrome trace_event vocabulary, which
 `repro.obs.export` renders verbatim):
@@ -34,8 +34,12 @@ never altered: traced and untraced runs are logit-byte-identical
 (tests/test_obs.py pins this).
 
 **Clocks.**  ``clock="wall"`` stamps `time.perf_counter_ns` (monotonic,
-microseconds in the export).  ``clock="tick"`` stamps a deterministic
-per-event sequence number instead — no wall time anywhere — so tests can
+microseconds in the export).  ``epoch_ns`` is the wall clock
+(`time.time_ns`) at timestamp 0, read back to back with the zero at
+construction and again at `clear`; the export writes it into the trace
+metadata, so a trace can be laid over a `jax.profiler` trace of the same
+process (whose session start is a wall-clock time too).
+``clock="tick"`` stamps a deterministic per-event sequence number instead — no wall time anywhere — so tests can
 pin the exact event sequence of a scheduling scenario across backends
 (ref vs fused produce the *same* trace, because the schedule is the same).
 
@@ -44,17 +48,33 @@ pin the exact event sequence of a scheduling scenario across backends
 allocation uses `itertools.count` / the wall clock, both safe under
 concurrent emitters, and the ring buffer is a `collections.deque`, whose
 ``append`` is atomic.
+
+**Scheduling samples.**  ``sched_begin`` / ``sched_end`` bracket a unit of
+work on one thread (the batcher's tick) and emit one ``"sched"`` counter:
+``runq_ms``, the thread's time runnable but waiting for a CPU
+(``/proc/thread-self/schedstat``, left out where the file is missing), and
+``gc_ms``, the time the garbage collector's passes took meanwhile.  For
+the latter an enabled tracer installs one `gc.callbacks` hook, which only
+adds each pass's duration to an integer: it never touches the ring
+buffer, since a pass can run while `events` copies it.  `close` removes
+the hook, and so does the tracer's collection.  `NullTracer` installs no
+hook, reads no file and no clock.
 """
 from __future__ import annotations
 
+import gc
 import itertools
+import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 CLOCKS = ("wall", "tick")
 DEFAULT_CAPACITY = 65536
+# per-thread scheduler statistics: ns on a CPU, ns runnable but waiting, slices
+SCHEDSTAT = "/proc/thread-self/schedstat"
 
 
 class Event(NamedTuple):
@@ -94,6 +114,12 @@ class _Span:
         tr._emit(Event("X", self._name, self._t0, tr._now() - self._t0,
                        tr._tid(), self._track, self._args))
 
+    def arg(self, key: str, value) -> None:
+        """Add one ``args`` entry known only once the span's work is done."""
+        if self._args is None:
+            self._args = {}
+        self._args[key] = value
+
 
 class _NullSpan:
     """The shared no-op span: entering/exiting records nothing."""
@@ -106,8 +132,43 @@ class _NullSpan:
     def __exit__(self, *exc) -> None:
         return None
 
+    def arg(self, key: str, value) -> None:
+        return None
+
 
 _NULL_SPAN = _NullSpan()
+
+
+class _GcClock:
+    """An enabled tracer's `gc.callbacks` hook: sums the collector's passes
+    in nanoseconds, and does nothing else."""
+
+    __slots__ = ("ns", "_start")
+
+    def __init__(self):
+        self.ns = 0
+        self._start = 0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._start = time.perf_counter_ns()
+        elif self._start:  # a hook installed mid-pass sees only its stop
+            self.ns += time.perf_counter_ns() - self._start
+            self._start = 0
+
+
+def _unhook(clock: _GcClock) -> None:
+    if clock in gc.callbacks:
+        gc.callbacks.remove(clock)
+
+
+def _runq_ns() -> int:
+    """The calling thread's run-queue wait so far, in nanoseconds."""
+    fd = os.open(SCHEDSTAT, os.O_RDONLY)
+    try:
+        return int(os.read(fd, 128).split()[1])
+    finally:
+        os.close(fd)
 
 
 class NullTracer:
@@ -126,6 +187,12 @@ class NullTracer:
         return None
 
     def counter(self, name: str, value, track: Optional[str] = None) -> None:
+        return None
+
+    def sched_begin(self) -> None:
+        return None
+
+    def sched_end(self, begin, track: Optional[str] = None) -> None:
         return None
 
     def events(self) -> List[Event]:
@@ -160,6 +227,12 @@ class Tracer:
         self._seq = itertools.count()
         self._emitted = 0
         self._t0 = time.perf_counter_ns()
+        self.epoch_ns = time.time_ns()
+        self._gc = _GcClock()
+        gc.callbacks.append(self._gc)
+        self._finalizer = weakref.finalize(self, _unhook, self._gc)
+        # a host without schedstat is not asked again on every sample
+        self._schedstat = os.path.exists(SCHEDSTAT)
         # thread ident -> (small tid, name); the feeder threads register
         # lazily with their thread name (ThreadPoolExecutor's prefix)
         self._threads: Dict[int, Tuple[int, str]] = {}
@@ -214,6 +287,21 @@ class Tracer:
         self._emit(Event("C", name, self._now(), 0, self._tid(), track,
                          dict(args)))
 
+    def sched_begin(self) -> Tuple[Optional[int], int]:
+        """Open a scheduling sample on the calling thread; pass the result
+        to `sched_end` on the same thread."""
+        return _runq_ns() if self._schedstat else None, self._gc.ns
+
+    def sched_end(self, begin: Tuple[Optional[int], int],
+                  track: Optional[str] = None) -> None:
+        """One ``"sched"`` counter sample over the work since ``begin``:
+        ``gc_ms``, and ``runq_ms`` where the thread's schedstat exists."""
+        runq0, gc0 = begin
+        args = {"gc_ms": (self._gc.ns - gc0) * 1e-6}
+        if runq0 is not None:
+            args["runq_ms"] = (_runq_ns() - runq0) * 1e-6
+        self._emit(Event("C", "sched", self._now(), 0, self._tid(), track, args))
+
     # -- inspection --------------------------------------------------------
 
     def events(self) -> List[Event]:
@@ -227,9 +315,15 @@ class Tracer:
         return self._emitted - len(self._buf)
 
     def clear(self) -> None:
-        """Drop all buffered events (the drop counter resets too)."""
+        """Drop all buffered events (the drop counter resets too) and read
+        the wall-clock anchor of timestamp 0 again."""
         self._buf.clear()
         self._emitted = 0
+        self.epoch_ns = time.time_ns() - (time.perf_counter_ns() - self._t0)
+
+    def close(self) -> None:
+        """Remove the collector hook (idempotent; the events stay)."""
+        self._finalizer()
 
     def __len__(self) -> int:
         return len(self._buf)

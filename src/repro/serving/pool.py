@@ -194,12 +194,13 @@ class SessionPool:
             raise PoolFullError(
                 f"all {self.pool_size} slots busy; evict before admitting"
             ) from None
-        if state is None:
-            self.state = clear_slot(self.state, slot)
-        else:
-            self.state = scatter_slot(self.state, slot, state)
-        if self.sharding is not None:
-            self.state = self._put(self.state)
+        with self.tracer.span("pool.admit", track=self.track, slot=slot):
+            if state is None:
+                self.state = clear_slot(self.state, slot)
+            else:
+                self.state = scatter_slot(self.state, slot, state)
+            if self.sharding is not None:
+                self.state = self._put(self.state)
         self._slots[slot] = stream_id
         self._slot_of[stream_id] = slot
         return slot
@@ -211,7 +212,8 @@ class SessionPool:
         the next `admit` overwrites it without any retrace."""
         slot = self._slot_of.pop(self._require(stream_id))
         self._slots[slot] = None
-        return gather_slot(self.state, slot)
+        with self.tracer.span("pool.evict", track=self.track, slot=slot):
+            return gather_slot(self.state, slot)
 
     def reset(self, stream_id: str) -> None:
         """Per-slot reset: zero this stream's ring and age in place, leaving

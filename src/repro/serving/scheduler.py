@@ -16,10 +16,10 @@ continuous batching scaled down to the paper's always-on sensor workload.
 
 Ticks are logical time: a request with ``arrival=k`` is admissible from
 tick k onward, which is how serve.py's simulation staggers sensors coming
-online.  The batcher records per-tick occupancy AND per-tick wall latency
-(tagged with the pool size it ran at) so the serving report can say how
-full the fixed-shape batch actually ran and what the p50/p99 tick latency
-was per bucket size (`benchmarks/serving_bench.py`).
+online.  The batcher records the mean occupancy over its ticks AND
+per-tick wall latency (tagged with the pool size it ran at) so the serving
+report can say how full the fixed-shape batch actually ran and what the
+p50/p99 tick latency was per bucket size (`benchmarks/serving_bench.py`).
 
 Fleet hooks (used by `repro.serving.fleet`, inert otherwise):
 
@@ -177,7 +177,9 @@ class ContinuousBatcher:
         self.results: List[StreamResult] = []
         self.cancelled: List[str] = []
         self.tick_index = 0
-        self.occupancy_trace: List[float] = []
+        # mean occupancy over every tick, in constant memory
+        self._occupancy_sum = 0.0
+        self._occupancy_ticks = 0
         # (pool_size, seconds) per non-idle tick — the latency sample the
         # serving bench turns into p50/p99 per bucket size.  Bounded: the
         # deque keeps the newest LATENCY_WINDOW samples for exact
@@ -448,12 +450,52 @@ class ContinuousBatcher:
         ]
         self.feeder.prefetch(self.pool.pool_size, self.pool.frame_shape, items)
 
+    def _retire(self, stepping: List[str], out: Dict[str, jax.Array]) -> int:
+        """Advance each stepped stream's cursor; evict the streams whose
+        clip is done and record their results.  Returns how many departed."""
+        departed = 0
+        for sid in stepping:
+            self._next_frame[sid] += 1
+            req = self._inflight[sid]
+            gs = self._gate_state.get(sid)
+            if gs is not None:
+                gs.cursor = self._next_frame[sid]
+                gs.processed += 1
+                gs.last_logits = np.asarray(out[sid])
+            if self._next_frame[sid] >= req.frames.shape[0]:
+                self.pool.evict(sid)
+                self.results.append(
+                    StreamResult(
+                        stream_id=sid,
+                        logits=np.asarray(out[sid]),
+                        n_frames=int(req.frames.shape[0]),
+                        admitted_tick=self._admitted_tick[sid],
+                        finished_tick=self.tick_index,
+                        label=req.label,
+                        net=req.net,
+                        frames_processed=gs.processed if gs else -1,
+                        frames_skipped=gs.skipped if gs else 0,
+                    )
+                )
+                del self._inflight[sid], self._next_frame[sid]
+                del self._admitted_tick[sid]
+                departed += 1
+        return departed
+
     def tick(self) -> Dict[str, jax.Array]:
         """One scheduling round: admit -> step -> evict.  Returns the
         per-stream logits of every stream that consumed a frame.  A tick
         with nothing in flight (gap before the next arrival) only advances
-        logical time."""
+        logical time.
+
+        Spans, all children of ``tick``: ``gate.park``/``gate.scan`` (gated
+        only), ``admit`` (holding ``pool.admit``), ``assemble``, ``step``
+        (holding ``pool.step``), ``demux`` (the per-stream split of the
+        logits) and ``retire`` (cursors, evictions with their
+        ``pool.evict``, results with their host copies).  A non-idle tick
+        ends with one ``sched`` counter sample."""
         tr, track = self.tracer, self.track
+        sched = tr.sched_begin()
         with tr.span("tick", track=track, tick=self.tick_index):
             if self.gate is not None:
                 with tr.span("gate.park", track=track):
@@ -464,11 +506,10 @@ class ContinuousBatcher:
                 self._admit_ready()
             stepping = list(self._inflight)
             occupancy = len(stepping) / self.pool.pool_size
-            self.occupancy_trace.append(occupancy)
+            self._occupancy_sum += occupancy
+            self._occupancy_ticks += 1
             self._m_occupancy.set(occupancy)
             self._m_queue.set(len(self._queue))
-            tr.counter("occupancy", occupancy, track=track)
-            tr.counter("queue_depth", len(self._queue), track=track)
             if not stepping:
                 if self.feeder is not None:
                     self.feeder.invalidate()
@@ -479,38 +520,17 @@ class ContinuousBatcher:
                 batch, active = self._assemble()
             with tr.span("step", track=track, streams=len(stepping)):
                 logits = self.pool.step_prepared(batch, active)
-            out = {sid: logits[self.pool.slot_of(sid)] for sid in stepping}
-            for sid in stepping:
-                self._next_frame[sid] += 1
-                req = self._inflight[sid]
-                gs = self._gate_state.get(sid)
-                if gs is not None:
-                    gs.cursor = self._next_frame[sid]
-                    gs.processed += 1
-                    gs.last_logits = np.asarray(out[sid])
-                if self._next_frame[sid] >= req.frames.shape[0]:
-                    self.pool.evict(sid)
-                    self.results.append(
-                        StreamResult(
-                            stream_id=sid,
-                            logits=np.asarray(out[sid]),
-                            n_frames=int(req.frames.shape[0]),
-                            admitted_tick=self._admitted_tick[sid],
-                            finished_tick=self.tick_index,
-                            label=req.label,
-                            net=req.net,
-                            frames_processed=gs.processed if gs else -1,
-                            frames_skipped=gs.skipped if gs else 0,
-                        )
-                    )
-                    del self._inflight[sid], self._next_frame[sid]
-                    del self._admitted_tick[sid]
+            with tr.span("demux", track=track, streams=len(stepping)):
+                out = {sid: logits[self.pool.slot_of(sid)] for sid in stepping}
+            with tr.span("retire", track=track) as retire:
+                retire.arg("departed", self._retire(stepping, out))
             self._kick_feeder()
             self._m_frames.inc(len(stepping))
             self.latency_trace.append(
                 (self.pool.pool_size, time.perf_counter() - t0)
             )
             self.tick_index += 1
+            tr.sched_end(sched, track)
             return out
 
     def run(self, max_ticks: Optional[int] = None) -> List[StreamResult]:
@@ -535,7 +555,6 @@ class ContinuousBatcher:
         depth, in-flight count, mean pool occupancy, accuracy over the
         labeled requests, per-net completed/in-flight/queued breakdowns,
         and p50/p99 per-tick latency (over non-idle ticks)."""
-        occ = self.occupancy_trace
         done = self.results
         acc = [r.correct for r in done if r.correct is not None]
         per_net: Dict[str, Dict[str, int]] = {}
@@ -565,7 +584,8 @@ class ContinuousBatcher:
             "queue_depth": self.queue_depth,
             "inflight": self.inflight_count,
             "frames_processed": frames,
-            "mean_occupancy": float(np.mean(occ)) if occ else 0.0,
+            "mean_occupancy": self._occupancy_sum / self._occupancy_ticks
+            if self._occupancy_ticks else 0.0,
             "accuracy": float(np.mean(acc)) if acc else float("nan"),
             "per_net": per_net,
             "latency_ms_p50": float(np.percentile(lat, 50) * 1e3)

@@ -16,8 +16,13 @@ The contracts under test:
     ``json.loads``, spans nest properly per lane, and `validate_nesting`
     flags an artificially overlapped span.
 """
+import builtins
+import gc
 import json
+import os
 import threading
+import time
+import weakref
 
 import numpy as np
 import jax
@@ -47,6 +52,7 @@ from repro.serving import (
     FleetRouter,
     StreamRequest,
 )
+from repro.obs.tracer import SCHEDSTAT
 from repro.serving.scheduler import LATENCY_WINDOW
 
 GATE = ActivityGate(wake_threshold=8, park_threshold=3, park_after=2)
@@ -347,7 +353,8 @@ def test_traced_run_logits_byte_identical(deployed):
         assert (plain[sid] == traced[sid]).all()
     assert pool.trace_count == 1  # tracing never touches the jit cache
     spans = {e.name for e in tracer.events() if e.phase == "X"}
-    assert {"tick", "admit", "assemble", "step", "pool.step"} <= spans
+    assert {"tick", "admit", "assemble", "step", "pool.step", "demux",
+            "retire", "pool.admit", "pool.evict"} <= spans
     # and the untraced run really recorded nothing (NULL_TRACER inside)
     assert batcher.track in {e.track for e in tracer.events() if e.track}
 
@@ -427,6 +434,191 @@ def test_fleet_trace_lanes_and_instants(deployed):
     assert pb["net_a"]["ticks"] > 0 and pb["net_b"]["ticks"] > 0
     for lane in ("net_a", "net_b"):
         assert pb[lane]["phases"]["step"]["us"] > 0
+
+
+def _parents(events):
+    """{span index: name of the innermost span around it on its track}."""
+    spans = [e for e in events if e.phase == "X"]
+    out = {}
+    for i, e in enumerate(spans):
+        around = [o for o in spans if o is not e and o.track == e.track
+                  and o.ts <= e.ts and e.ts + e.dur <= o.ts + o.dur]
+        out[i] = min(around, key=lambda o: o.dur).name if around else None
+    return spans, out
+
+
+def test_tick_phase_spans_nest_and_count(deployed):
+    """demux / retire under tick, pool.admit under admit, pool.evict under
+    retire; demux's ``streams`` is what the tick stepped, retire's
+    ``departed`` what it finished."""
+    tracer = Tracer(clock="tick")
+    pool = deployed.serve(2, backend="fused")
+    batcher = ContinuousBatcher(pool, tracer=tracer)
+    clips = event_clips(3, 4)
+    for i, n in enumerate((2, 3, 4)):
+        batcher.submit(StreamRequest(f"s{i}", clips[i][:n], arrival=i))
+    stepped, departed = [], []
+    while batcher.pending:
+        done = len(batcher.results)
+        out = batcher.tick()
+        if out:
+            stepped.append(len(out))
+            departed.append(len(batcher.results) - done)
+    assert validate_nesting(to_chrome(tracer)) == []
+    spans, parent = _parents(tracer.events())
+    want = {"demux": "tick", "retire": "tick", "pool.admit": "admit",
+            "pool.evict": "retire", "admit": "tick", "step": "tick",
+            "pool.step": "step"}
+    for i, e in enumerate(spans):
+        if e.name in want:
+            assert parent[i] == want[e.name], (e.name, parent[i])
+    assert [e.args["streams"] for e in spans if e.name == "demux"] == stepped
+    assert [e.args["departed"] for e in spans if e.name == "retire"] == departed
+    assert sum(e.name == "pool.admit" for e in spans) == 3
+    assert sum(e.name == "pool.evict" for e in spans) == 3
+    assert all(isinstance(e.args["slot"], int) for e in spans
+               if e.name in ("pool.admit", "pool.evict"))
+
+
+def test_one_sched_sample_per_non_idle_tick(deployed):
+    tracer = Tracer()
+    pool = deployed.serve(2, backend="fused")
+    batcher = ContinuousBatcher(pool, tracer=tracer)
+    clips = event_clips(2, 3)
+    batcher.submit(StreamRequest("early", clips[0], arrival=0))
+    batcher.submit(StreamRequest("late", clips[1], arrival=6))  # idle gap
+    busy = sum(bool(batcher.tick()) for _ in range(12))
+    assert batcher.tick_index == 12 and 0 < busy < 12
+    samples = [e for e in tracer.events() if e.phase == "C" and e.name == "sched"]
+    assert len(samples) == busy
+    has_runq = os.path.exists(SCHEDSTAT)
+    for e in samples:
+        assert e.track == batcher.track
+        assert e.args["gc_ms"] >= 0
+        assert ("runq_ms" in e.args) == has_runq
+        assert e.args.get("runq_ms", 0) >= 0
+    # the removed per-tick counters stay removed
+    assert {e.name for e in tracer.events() if e.phase == "C"} == {"sched"}
+
+
+def test_sched_sample_without_schedstat_leaves_runq_out(monkeypatch):
+    import repro.obs.tracer as tracer_mod
+
+    monkeypatch.setattr(tracer_mod, "SCHEDSTAT", "/proc/thread-self/no-such-file")
+    tr = Tracer(clock="tick")
+    tr.sched_end(tr.sched_begin(), track="a")
+    (e,) = tr.events()
+    assert (e.phase, e.name, e.track) == ("C", "sched", "a")
+    assert set(e.args) == {"gc_ms"}
+    tr.close()
+
+
+def test_untraced_batcher_hooks_no_gc_and_reads_no_proc(deployed, monkeypatch):
+    clips = event_clips(3, 4)
+    _drive_pool(deployed, clips)  # warm every program the run dispatches
+    gc.collect()  # finalize tracers of earlier tests: their hooks go now
+    hooks = list(gc.callbacks)
+    opened = []
+    real_open, real_os_open = builtins.open, os.open
+
+    def spy_open(file, *a, **k):
+        opened.append(str(file))
+        return real_open(file, *a, **k)
+
+    def spy_os_open(path, *a, **k):
+        opened.append(str(path))
+        return real_os_open(path, *a, **k)
+
+    monkeypatch.setattr(builtins, "open", spy_open)
+    monkeypatch.setattr(os, "open", spy_os_open)
+    _drive_pool(deployed, clips)
+    monkeypatch.undo()
+    assert gc.callbacks == hooks
+    assert not [p for p in opened if p.startswith("/proc")]
+
+
+def test_tracer_gc_hook_removed_on_close_and_collection():
+    tr = Tracer()
+    hook = tr._gc
+    assert hook in gc.callbacks
+    tr.close()
+    tr.close()  # idempotent
+    assert hook not in gc.callbacks
+    tr = Tracer()
+    hook, ref = tr._gc, weakref.ref(tr)
+    assert hook in gc.callbacks
+    del tr
+    gc.collect()
+    assert ref() is None and hook not in gc.callbacks
+
+
+def test_forced_collection_inside_a_tick_shows_in_gc_ms(deployed, monkeypatch):
+    tracer = Tracer()
+    pool = deployed.serve(2, backend="fused")
+    batcher = ContinuousBatcher(pool, tracer=tracer)
+    clips = event_clips(2, 4)
+    for i in range(2):
+        batcher.submit(StreamRequest(f"s{i}", clips[i]))
+    batcher.tick()  # warm
+    assemble = batcher._assemble
+
+    def assemble_after_a_collection():
+        garbage = [[i] for i in range(20000)]  # a pass with work to do
+        del garbage
+        gc.collect()
+        return assemble()
+
+    monkeypatch.setattr(batcher, "_assemble", assemble_after_a_collection)
+    batcher.tick()
+    last = [e for e in tracer.events() if e.name == "sched"][-1]
+    assert last.args["gc_ms"] > 0
+    tracer.close()
+
+
+def test_null_tracer_samples_nothing():
+    begin = NULL_TRACER.sched_begin()
+    assert begin is None
+    assert NULL_TRACER.sched_end(begin, track="a") is None
+    with NULL_TRACER.span("retire") as span:
+        span.arg("departed", 1)
+    assert NULL_TRACER.events() == []
+
+
+def test_wall_clock_anchor_places_spans():
+    """``epoch_ns`` + a span's ts is the wall time the span began, within
+    1 ms, before and after `clear`."""
+    tr = Tracer()
+    for _ in range(2):
+        wall = time.time_ns()
+        with tr.span("x", track="a"):
+            pass
+        doc = to_chrome(tr)
+        (x,) = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+        assert abs(doc["otherData"]["epoch_ns"] + x["ts"] * 1000 - wall) < 1e6
+        time.sleep(0.01)
+        tr.clear()
+    assert "epoch_ns" not in to_chrome(Tracer(clock="tick"))["otherData"]
+
+
+def test_mean_occupancy_is_the_mean_over_ticks(deployed):
+    """The running mean equals the per-tick occupancies the trace gives
+    (demux ``streams`` over the pool size; 0 on idle ticks)."""
+    tracer = Tracer(clock="tick")
+    pool = deployed.serve(3, backend="fused")
+    batcher = ContinuousBatcher(pool, tracer=tracer)
+    clips = event_clips(4, 3)
+    for i, arrival in enumerate((0, 0, 5, 6)):
+        batcher.submit(StreamRequest(f"s{i}", clips[i], arrival=arrival))
+    batcher.run()
+    occ = []
+    for e in sorted(tracer.events(), key=lambda e: e.ts):  # by start
+        if e.name == "tick":
+            occ.append(0.0)
+        elif e.name == "demux":
+            occ[-1] = e.args["streams"] / 3
+    assert len(occ) == batcher.tick_index and 0 in occ
+    assert batcher.stats()["mean_occupancy"] == pytest.approx(np.mean(occ))
+    assert not hasattr(batcher, "occupancy_trace")
 
 
 # ---------------------------------------------------------------------------
