@@ -23,9 +23,9 @@ The serving tick (`ContinuousBatcher.tick`), on the batcher's track:
       assemble                host batch assembly
       step                    dispatch of the jitted step (``streams``)
         pool.step             the jitted call (``pool_size``)
-      demux                   per-stream split of the logits (``streams``)
-      retire                  cursors, evictions, results with their host
-                              copies (``departed``)
+      demux                   one host copy of the step's logits and its
+                              per-stream rows; waits for the step (``streams``)
+      retire                  cursors, evictions, results (``departed``)
         pool.evict            `SessionPool.evict`: gather the slot (``slot``)
     sched (counter)           one per non-idle tick, at its end: ``gc_ms``,
                               ``runq_ms`` of the ticking thread

@@ -26,8 +26,10 @@ means many tenants running *different* registry nets concurrently — the
   * **Async host-side ingestion.**  The deploy step is a pure function of
     ring state, so host ingestion and device compute pipeline cleanly: a
     `FrameFeeder` thread assembles the NEXT tick's `[P, H, W, C]` frame
-    batch into pinned double buffers while the device executes the current
-    step.  Falls back to synchronous assembly when threads are unavailable
+    batch into pinned double buffers while the rest of the round runs (a
+    bucket's tick returns host logits, so its own step is done by then;
+    the fill overlaps the other buckets' steps and the caller).  Falls
+    back to synchronous assembly when threads are unavailable
     (``ingest="sync"``, or a failed thread spawn) — results are
     bit-identical either way (tested).
   * **Admission overflow -> bounded FIFO.**  A full pool spills arrivals
@@ -120,11 +122,11 @@ class FrameFeeder:
 
     The pool step is a pure function of (ring state, frame batch), and the
     NEXT tick's stream->frame assignment is host-side bookkeeping (clip
-    cursors), so the host can assemble tick t+1's batch while the device
-    executes tick t.  `prefetch` schedules the assembly (on the thread, or
-    inline in sync mode); `take` joins and hands the batch over; buffers
-    alternate per prefetch so the one the device just copied from is the
-    one being refilled.  The batcher patches the prefetched batch for
+    cursors), so a thread can assemble tick t+1's batch while the rest of
+    round t runs (the other buckets' ticks, the caller).  `prefetch`
+    schedules the assembly (on the thread, or inline in sync mode); `take`
+    joins and hands the batch over; buffers alternate per prefetch so the
+    one the device just copied from is the one being refilled.  The batcher patches the prefetched batch for
     admissions/cancellations that happened after the prefetch, so the
     pipelining is invisible to the numerics (async == sync bit-identical,
     tested in tests/test_fleet.py).

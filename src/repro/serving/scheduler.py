@@ -5,7 +5,10 @@
 one `tick()` per wall-clock step that (1) admits queued streams into free
 slots, (2) steps every in-flight stream by its next frame, (3) evicts
 finished streams — so a departing stream's slot is refilled on the very
-next tick without ever retracing the jitted step.  This is vLLM-style
+next tick without ever retracing the jitted step.  `tick()` returns each
+stepped stream's `[n_classes]` logits as host rows of ONE device-to-host
+copy of the step's `[P, n_classes]` logits: one transfer per tick (per
+chip on a sharded pool), never one per stream.  This is vLLM-style
 continuous batching scaled down to the paper's always-on sensor workload.
 
     pool = deployed.serve(pool_size=4)
@@ -24,9 +27,10 @@ p50/p99 tick latency was per bucket size (`benchmarks/serving_bench.py`).
 Fleet hooks (used by `repro.serving.fleet`, inert otherwise):
 
   * ``feeder`` — an async ingestion double-buffer (`fleet.FrameFeeder`):
-    when present, `tick()` consumes the batch the feeder assembled during
-    the *previous* device step and kicks off assembly of the next one, so
-    host ingestion and device compute pipeline.
+    when present, `tick()` consumes the batch the feeder assembled since
+    the *previous* tick and kicks off assembly of the next one once its
+    logits are on the host, so ingestion overlaps whatever runs between
+    ticks (a fleet's other buckets, the caller).
   * `swap_pool(new_pool)` — migrate every in-flight stream into another
     (typically differently-sized) pool via evict/admit-with-state, which
     is how autoscaling rides the bucket ladder with bit-identical logits.
@@ -438,7 +442,7 @@ class ContinuousBatcher:
 
     def _kick_feeder(self) -> None:
         """Start assembling the NEXT tick's batch on the feeder thread
-        while the device is still chewing on the one just dispatched.
+        while the caller runs on until the next tick.
         Every stream still in flight here steps next tick (finished ones
         were just evicted), so the assignment is exact modulo admissions,
         which `_assemble` patches in at consume time."""
@@ -450,7 +454,7 @@ class ContinuousBatcher:
         ]
         self.feeder.prefetch(self.pool.pool_size, self.pool.frame_shape, items)
 
-    def _retire(self, stepping: List[str], out: Dict[str, jax.Array]) -> int:
+    def _retire(self, stepping: List[str], out: Dict[str, np.ndarray]) -> int:
         """Advance each stepped stream's cursor; evict the streams whose
         clip is done and record their results.  Returns how many departed."""
         departed = 0
@@ -461,13 +465,13 @@ class ContinuousBatcher:
             if gs is not None:
                 gs.cursor = self._next_frame[sid]
                 gs.processed += 1
-                gs.last_logits = np.asarray(out[sid])
+                gs.last_logits = out[sid]
             if self._next_frame[sid] >= req.frames.shape[0]:
                 self.pool.evict(sid)
                 self.results.append(
                     StreamResult(
                         stream_id=sid,
-                        logits=np.asarray(out[sid]),
+                        logits=out[sid],
                         n_frames=int(req.frames.shape[0]),
                         admitted_tick=self._admitted_tick[sid],
                         finished_tick=self.tick_index,
@@ -482,18 +486,20 @@ class ContinuousBatcher:
                 departed += 1
         return departed
 
-    def tick(self) -> Dict[str, jax.Array]:
-        """One scheduling round: admit -> step -> evict.  Returns the
-        per-stream logits of every stream that consumed a frame.  A tick
-        with nothing in flight (gap before the next arrival) only advances
-        logical time.
+    def tick(self) -> Dict[str, np.ndarray]:
+        """One scheduling round: admit -> step -> evict.  Returns, for
+        every stream that consumed a frame, its `[n_classes]` logits on the
+        host: rows of one host copy of the step's `[P, n_classes]` logits,
+        so the step has finished when this returns.  A tick with nothing
+        in flight (gap before the next arrival) only advances logical time.
 
         Spans, all children of ``tick``: ``gate.park``/``gate.scan`` (gated
         only), ``admit`` (holding ``pool.admit``), ``assemble``, ``step``
-        (holding ``pool.step``), ``demux`` (the per-stream split of the
-        logits) and ``retire`` (cursors, evictions with their
-        ``pool.evict``, results with their host copies).  A non-idle tick
-        ends with one ``sched`` counter sample."""
+        (holding ``pool.step``), ``demux`` (one host copy of the step's
+        logits and its per-stream rows; the copy waits for the step to
+        finish) and ``retire`` (cursors, evictions with their
+        ``pool.evict``, results).  A non-idle tick ends with one ``sched``
+        counter sample."""
         tr, track = self.tracer, self.track
         sched = tr.sched_begin()
         with tr.span("tick", track=track, tick=self.tick_index):
@@ -521,7 +527,8 @@ class ContinuousBatcher:
             with tr.span("step", track=track, streams=len(stepping)):
                 logits = self.pool.step_prepared(batch, active)
             with tr.span("demux", track=track, streams=len(stepping)):
-                out = {sid: logits[self.pool.slot_of(sid)] for sid in stepping}
+                rows = jax.device_get(logits)  # one transfer per tick
+                out = {sid: rows[self.pool.slot_of(sid)] for sid in stepping}
             with tr.span("retire", track=track) as retire:
                 retire.arg("departed", self._retire(stepping, out))
             self._kick_feeder()

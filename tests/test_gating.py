@@ -264,6 +264,10 @@ class TestGatedBatcher:
         gs = bat._gate_state["s0"]
         assert "s0" in bat._parked
         assert not gs.awake and gs.processed == 4  # frames 0..3 ran
+        # the parked stream keeps the host logits of its last processed frame
+        assert isinstance(gs.last_logits, np.ndarray)
+        np.testing.assert_array_equal(
+            gs.last_logits, replay(deployed, clip, [0, 1, 2, 3], "ref"))
         # the pool retains per-slot state (no batch dim); a batch-1 lone
         # session carries a leading batch axis — bridge it explicitly
         session = deployed.stream(batch=1, backend="ref")
@@ -282,8 +286,10 @@ class TestGatedBatcher:
         gs.retained = roundtripped  # resume from the round-tripped state
         (r,) = bat.run()
         assert r.frames_processed == 6 and bat.stats()["gating"]["wakes"] == 2
+        assert isinstance(r.logits, np.ndarray)
         np.testing.assert_array_equal(
             r.logits, replay(deployed, clip, processed_frames(clip), "ref"))
+        np.testing.assert_array_equal(gs.last_logits, r.logits)
 
     def test_cancel_parked_stream(self, deployed):
         clip = np.zeros((6, 4, 4, 2), np.float32)  # all quiet: parks forever

@@ -294,6 +294,40 @@ class TestContinuousBatcher:
             exact(r.logits, np.asarray(want)[0])
             assert r.n_frames == 4 and r.finished_tick >= r.admitted_tick
 
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_tick_returns_rows_of_one_host_array(self, deployed, backend):
+        """Each non-empty tick hands back host rows of ONE `[P, n_classes]`
+        copy of the step's logits, through departures and refills, and each
+        row is bit-exact vs a lone session fed the same frames."""
+        P, n_classes = 3, deployed.graph.n_classes
+        lengths = [2, 3, 4, 1, 3, 2, 4]
+        frames = clips_for(deployed.graph, len(lengths), max(lengths), seed=31)
+        batcher = ContinuousBatcher(SessionPool(deployed, P, backend=backend))
+        oracles = {}
+        for i, n in enumerate(lengths):
+            batcher.submit(StreamRequest(f"s{i}", frames[i, :n]))
+            oracles[f"s{i}"] = deployed.stream(batch=1, backend=backend)
+        fed = {sid: 0 for sid in oracles}
+        ticks = 0
+        while batcher.pending:
+            out = batcher.tick()
+            ticks += 1
+            assert out
+            (base,) = {id(y.base): y.base for y in out.values()}.values()
+            assert isinstance(base, np.ndarray)
+            assert base.shape == (P, n_classes) and base.dtype == np.float32
+            for sid, y in out.items():
+                assert isinstance(y, np.ndarray) and y.shape == (n_classes,)
+                assert np.shares_memory(y, base)
+                i, t = int(sid[1:]), fed[sid]
+                want = oracles[sid].step(frames[i:i + 1, t])
+                exact(y, np.asarray(want)[0])
+                fed[sid] += 1
+        assert fed == {f"s{i}": n for i, n in enumerate(lengths)}
+        assert ticks < sum(lengths)       # slots were shared, then refilled
+        for r in batcher.results:
+            assert isinstance(r.logits, np.ndarray)
+
     def test_future_head_does_not_block_admissible_streams(self, deployed):
         """A far-future request at the head of the queue must not starve a
         later-submitted stream whose arrival has already passed."""
@@ -501,6 +535,19 @@ for t in range(3):
     a, b = sharded.step(fr), plain.step(fr)
     for k in fr:
         np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+from repro.serving import ContinuousBatcher, StreamRequest
+outs = []
+for pool in (SessionPool(dep, 4, backend="ref", sharding="auto"),
+             SessionPool(dep, 4, backend="ref")):
+    bat = ContinuousBatcher(pool)
+    for i in range(4):
+        bat.submit(StreamRequest(f"s{i}", frames[i, :1 + i % 3]))
+    outs.append([bat.tick() for _ in range(3)])
+for got, want in zip(*outs):
+    assert got.keys() == want.keys()
+    for k in got:
+        assert isinstance(got[k], np.ndarray)
+        np.testing.assert_array_equal(got[k], want[k])
 print("SHARDED-OK")
 """
 
