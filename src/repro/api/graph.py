@@ -4,9 +4,10 @@ A `CutieGraph` is a flat, ordered tuple of `LayerSpec`s over the layer kinds
 the CUTIE datapath executes:
 
   * ``conv2d``      — SAME ternary convolution (the OCU array's native op;
-                      3x3 by default, 1x1 for pointwise layers, and an
-                      optional output ``stride`` realized as a post-ternarize
-                      subsample so every backend shares one conv kernel)
+                      3x3 by default, 1x1 for pointwise layers, an optional
+                      output ``stride`` realized as a post-ternarize
+                      subsample so every backend shares one conv kernel, and
+                      an optional residual ``shortcut`` from an earlier conv)
   * ``pool``        — 2x2 max pool (the silicon's inter-layer pooling unit)
   * ``global_pool`` — spatial global average (DVS frontend -> feature vector)
   * ``flatten``     — [B,H,W,C] -> [B,H*W*C] (CIFAR head)
@@ -21,6 +22,21 @@ once per sensor frame, pushes one feature vector into the 24-step TCN ring
 memory, and the TCN head classifies over the ordered window.  A graph with
 no temporal layers (CIFAR) is a plain one-shot classifier.
 
+Layers form a chain — each consumes the previous layer's output — with one
+exception, the residual shortcut: a conv2d with ``shortcut = k`` (the index
+in ``layers`` of an earlier conv2d) adds layer k's ternary output ``a_k`` to
+its scaled accumulator before the threshold,
+
+    y_i = scale_i * conv(a_{i-1}, T_i) + S(a_k),    a_i = ternarize(y_i),
+
+where ``S`` is the identity when the shapes match and otherwise He et al.'s
+option A: keep every 2nd row and column from the top-left (as ``stride``
+does) and append zero channels up to ``c_out``.  ``validate`` refuses every
+other shape relation, a shortcut on a strided conv or on a conv a pool
+follows, and a shortcut whose source a pool follows.  Every interpreter —
+the QAT and deploy forwards, `repro.sim`'s plan and executor, the ``.cutie``
+artifact — keeps ``a_k`` live until its consumer.
+
 The graph is also the single source of truth for the analytical silicon
 model: `repro.api.program.export_conv_layers` lowers it to
 `core.cutie_arch.ConvLayer`s, so `deployed.silicon_report()` closes the loop
@@ -29,7 +45,7 @@ between the JAX model and the paper's Table 1 numbers.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 _TEMPORAL_KINDS = ("tcn", "last_step")
 _WEIGHT_KINDS = ("conv2d", "tcn", "fc")
@@ -48,6 +64,7 @@ class LayerSpec:
     dilation: int = 1    # tcn: dilation D
     window: int = 2      # pool: window/stride
     stride: int = 1      # conv2d: output stride (post-ternarize subsample)
+    shortcut: Optional[int] = None  # conv2d: index of the residual's source conv
 
     @property
     def has_weights(self) -> bool:
@@ -55,16 +72,19 @@ class LayerSpec:
 
 
 def conv2d(
-    c_in: int, c_out: int, kernel: Tuple[int, int] = (3, 3), stride: int = 1
+    c_in: int, c_out: int, kernel: Tuple[int, int] = (3, 3), stride: int = 1,
+    shortcut: Optional[int] = None,
 ) -> LayerSpec:
     """SAME ternary 2-D convolution — the OCU array's native op.  ``kernel``
     may be ``(1, 1)`` for a pointwise layer.  ``stride > 1`` subsamples the
     ternarized output (top-left phase) — because ternarization is
     elementwise, subsampling after it is bit-identical to a strided conv,
     so all backends reuse the one SAME-conv kernel.  A strided conv never
-    absorbs a following pool (`CutieGraph.conv_pool_plan`)."""
+    absorbs a following pool (`CutieGraph.conv_pool_plan`).  ``shortcut``
+    names an earlier conv2d whose output is added before the threshold
+    (module docstring)."""
     return LayerSpec(kind="conv2d", c_in=c_in, c_out=c_out, kernel=kernel,
-                     stride=stride)
+                     stride=stride, shortcut=shortcut)
 
 
 def pool(window: int = 2) -> LayerSpec:
@@ -179,7 +199,36 @@ class CutieGraph:
                 return l.c_in
         raise ValueError(f"{self.name}: no tcn layer")
 
+    @property
+    def shortcut_sources(self) -> Tuple[int, ...]:
+        """Indices of the conv2d layers whose output a later layer's
+        shortcut reads — the maps an interpreter must keep live."""
+        return tuple(sorted({l.shortcut for l in self.layers
+                             if l.shortcut is not None}))
+
     # -- validation --------------------------------------------------------
+
+    def _check_shortcut(self, i: int, out: Dict[int, Tuple[int, int, int]],
+                        shape: Tuple[int, int, int]) -> None:
+        """Layer ``i``'s shortcut against the maps already computed
+        (``out``: conv index -> its output (h, w, c)) and its own output
+        ``shape``: identity or option A, nothing else."""
+        l, where = self.layers[i], f"{self.name} layer {i} (conv2d)"
+        k = l.shortcut
+        nxt = self.layers[i + 1].kind if i + 1 < len(self.layers) else None
+        if not 0 <= k < i or self.layers[k].kind != "conv2d":
+            raise ValueError(f"{where}: shortcut {k} is not an earlier conv2d")
+        if l.stride > 1 or nxt == "pool":
+            raise ValueError(f"{where}: a shortcut needs stride 1 and no pool after")
+        if self.layers[k + 1].kind == "pool":
+            raise ValueError(f"{where}: shortcut source {k} is followed by a pool")
+        (hk, wk, ck), (h, w, c) = out[k], shape
+        if (hk, wk, ck) != (h, w, c) and not (
+                (hk, wk) == (2 * h, 2 * w) and ck <= c):
+            raise ValueError(
+                f"{where}: shortcut map {hk}x{wk}x{ck} is neither {h}x{w}x{c} "
+                f"nor its option-A source {2 * h}x{2 * w}x(<= {c})"
+            )
 
     def validate(self) -> "CutieGraph":
         """Shape-chain the graph; raises ValueError on inconsistency."""
@@ -187,6 +236,7 @@ class CutieGraph:
         c = self.input_ch
         seen_temporal = False
         flat: Optional[int] = None  # features after flatten, None otherwise
+        conv_out: Dict[int, Tuple[int, int, int]] = {}
         for i, l in enumerate(self.layers):
             where = f"{self.name} layer {i} ({l.kind})"
             if l.kind in _TEMPORAL_KINDS:
@@ -204,6 +254,9 @@ class CutieGraph:
                     )
                 h, w = h // l.stride, w // l.stride
                 c = l.c_out
+                if l.shortcut is not None:
+                    self._check_shortcut(i, conv_out, (h, w, c))
+                conv_out[i] = (h, w, c)
             elif l.kind == "pool":
                 if h % l.window or w % l.window:
                     raise ValueError(f"{where}: {h}x{w} not divisible by {l.window}")

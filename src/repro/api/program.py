@@ -119,34 +119,47 @@ def _ternarize(y: jax.Array, threshold: float) -> jax.Array:
 
 def _dispatch_conv(x, packed, eff_scale, backend: str, *,
                    threshold=0.5, pool: int = 0,
-                   block_cout: Optional[int] = None):
+                   block_cout: Optional[int] = None, residual=None):
     """One SAME ternary conv through the selected backend.  ``x`` must
     already be channel-padded to 4 * packed.shape[2].  ``threshold`` is a
     scalar or per-channel [C_out] vector (the ThFU comparator constants).
     ``block_cout`` is the layer's plan-driven kernel block
-    (`kernels.autotune`; None = the plan-less 128 default).
+    (`kernels.autotune`; None = the plan-less 128 default).  ``residual``
+    is the layer's shortcut map at its output size (`shortcut_map`), added
+    to the scaled accumulator before the threshold.
 
     The "fused" backend runs the whole CUTIE layer — conv, per-OCU scale,
-    threshold unit, optional ``pool``-window max-pool — in a single packed
-    launch (native select-decode datapath on CPU, the Pallas kernel on TPU)
-    and emits int8 ternary activations; "pallas"/"interpret" pin the Pallas
-    machinery (compiled/interpreted), return the scaled float accumulator,
-    and leave ternarize/pool to the caller."""
+    shortcut add, threshold unit, optional ``pool``-window max-pool — in a
+    single packed launch (native select-decode datapath on CPU, the Pallas
+    kernel on TPU) and emits int8 ternary activations; "pallas"/"interpret"
+    pin the Pallas machinery (compiled/interpreted), return the scaled
+    float accumulator with the shortcut added, and leave ternarize/pool to
+    the caller."""
     check_backend(backend)
     if backend == "ref":
-        return ternary_conv2d_ref(x, packed, eff_scale)
-    if backend == "interpret":
-        return ternary_conv2d(
-            x, packed, eff_scale, impl="interpret", block_cout=block_cout
-        )
+        return ternary_conv2d_ref(x, packed, eff_scale, residual=residual)
     if backend == "fused":
         return ternary_conv2d(
             x, packed, eff_scale, fuse_ternary=True, threshold=threshold,
             fuse_pool=pool, out_dtype=jnp.int8, block_cout=block_cout,
+            residual=residual,
         )
     return ternary_conv2d(
-        x, packed, eff_scale, impl="pallas", block_cout=block_cout
+        x, packed, eff_scale, block_cout=block_cout, residual=residual,
+        impl="interpret" if backend == "interpret" else "pallas",
     )
+
+
+def shortcut_map(a: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
+    """``S(a_k)``: a saved map [B, H', W', C'] brought to a conv output of
+    ``shape`` [B, H, W, C] — the identity where they match, else option A:
+    every (H'/H)-th row and column from the top-left, as ``stride`` keeps
+    them, and zero channels appended up to C.  `CutieGraph.validate` has
+    refused every other relation."""
+    s = a.shape[1] // shape[1]
+    if s > 1:
+        a = a[:, ::s, ::s, :]
+    return _pad_channels(a, shape[-1])
 
 
 def _pad_channels(x: jax.Array, c: int) -> jax.Array:
@@ -265,7 +278,8 @@ class CutieProgram:
         g = self.graph
         nu = g.weight_nu if nu is None else nu
         ci = 0
-        for l in g.spatial_layers:
+        sources, saved = g.shortcut_sources, {}  # saved: maps kept live
+        for i, l in enumerate(g.spatial_layers):
             if l.kind == "conv2d":
                 axis = (0, 1, 2) if g.qat_per_channel else None
                 wq = ste_ternary_weights(params["conv"][ci]["w"], nu, axis)
@@ -276,14 +290,17 @@ class CutieProgram:
                 sd = _bn_sd(y)
                 if _record is not None:
                     _record.append(sd)
-                x = ste_ternary_acts(
-                    y / (sd + _BN_EPS), self._qat_threshold(params, "conv", ci)
-                )
+                y = y / (sd + _BN_EPS)
+                if l.shortcut is not None:
+                    y = y + shortcut_map(saved.pop(l.shortcut), y.shape)
+                x = ste_ternary_acts(y, self._qat_threshold(params, "conv", ci))
                 if l.stride > 1:
                     # stride = post-ternarize subsample (top-left phase);
                     # ternarization is elementwise, so this is bit-identical
                     # to a strided conv and every backend shares one kernel
                     x = x[:, :: l.stride, :: l.stride, :]
+                if i in sources:
+                    saved[i] = x
                 ci += 1
             elif l.kind == "pool":
                 x = _pool(x, l.window)
@@ -498,19 +515,26 @@ class DeployedProgram:
         feature vector / logits.  On the "fused" backend each conv layer is
         one kernel launch (conv+scale+ternarize, plus the following pool
         layer sunk into the epilogue) emitting int8 ternary activations —
-        the pool LayerSpec it absorbed is then skipped here."""
+        the pool LayerSpec it absorbed is then skipped here.  A shortcut
+        source's output stays live until its consumer, whose launch takes
+        it as the residual operand."""
         if backend == "bitsim":
             return self._bitsim().spatial_forward(x)
         g = self.graph
         ci = 0
         fused_pools = 0
         blocks = None if backend == "ref" else self.kernel_blocks["conv"]
-        for l in g.spatial_layers:
+        sources, saved = g.shortcut_sources, {}  # saved: maps kept live
+        for i, l in enumerate(g.spatial_layers):
             if l.kind == "conv2d":
                 entry = self.tables["conv"][ci]
                 bc = None if blocks is None else blocks[ci].block_cout
                 ci += 1
                 c_pad = 4 * entry["packed"].shape[2]
+                res = None
+                if l.shortcut is not None:
+                    res = shortcut_map(saved.pop(l.shortcut),
+                                       (*x.shape[:3], l.c_out))
                 x = _pad_channels(x, c_pad)
                 eff = self._eff_scale(entry, l.kernel[0] * l.kernel[1] * c_pad)
                 if backend == "fused":
@@ -518,18 +542,20 @@ class DeployedProgram:
                     x = _dispatch_conv(
                         x, entry["packed"], eff, backend,
                         threshold=entry.get("threshold", g.act_threshold), pool=pool,
-                        block_cout=bc,
+                        block_cout=bc, residual=res,
                     )
                     fused_pools += 1 if pool else 0
                 else:
                     y = _dispatch_conv(x, entry["packed"], eff, backend,
-                                       block_cout=bc)
+                                       block_cout=bc, residual=res)
                     x = _ternarize(y, entry.get("threshold", g.act_threshold))
                 if l.stride > 1:
                     # post-ternarize subsample == strided conv (elementwise
                     # epilogue); a strided conv never absorbs a pool, so the
                     # fused int8 output subsamples the same way
                     x = x[:, :: l.stride, :: l.stride, :]
+                if i in sources:
+                    saved[i] = x
             elif l.kind == "pool":
                 if fused_pools:
                     fused_pools -= 1

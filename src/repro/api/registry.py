@@ -19,6 +19,11 @@ into a dilated-TCN head.  It exists to exercise the stride/1x1 layer
 kinds end to end (lower -> bitsim -> fused -> ``.cutie`` artifact) and is
 the always-on workload the activity gate duty-cycles in serving.
 
+And ``resnet20_tnn`` — He et al.'s CIFAR-10 ResNet-20 (arXiv:1512.03385
+§4.2), ternarised as in TWN/TTQ: the registry's first non-chain net, whose
+residual shortcuts (identity, and option A where the width doubles) run in
+the conv kernel's epilogue.
+
 Legacy aliases ``cutie_cifar10`` / ``cutie_dvs`` map to the same graphs.
 """
 from __future__ import annotations
@@ -235,10 +240,46 @@ def kws_tcn_graph(
     )
 
 
+def resnet20_tnn_graph(
+    widths: Tuple[int, int, int] = (16, 32, 64),
+    blocks: int = 3,
+    n_classes: int = 10,
+    input_hw: Tuple[int, int] = (32, 32),
+    name: str = "resnet20_tnn",
+) -> CutieGraph:
+    """The 6n+2 CIFAR ResNet of He et al. (§4.2) at n = ``blocks``: a 3x3
+    stem into ``widths[0]`` channels, three stages of ``blocks`` basic
+    blocks at ``widths`` (stride 2 at the first conv of stages 2 and 3),
+    a global average pool and an fc.  Each block is two convs, the second
+    taking a shortcut to the block's input: identity, or option A (every
+    2nd row and column, zero channels appended) where the stage halves the
+    map and widens it.  Ternary activations at the threshold stand in for
+    ReLU and BN folds into the per-channel scale, as in every CUTIE net.
+    ``input_hw`` must be divisible by 4 (two stride-2 stages)."""
+    layers = [conv2d(3, widths[0])]
+    c = widths[0]
+    for stage, width in enumerate(widths):
+        for block in range(blocks):
+            src = len(layers) - 1  # the block's input: the last conv's output
+            stride = 2 if stage > 0 and block == 0 else 1
+            layers += [conv2d(c, width, stride=stride),
+                       conv2d(width, width, shortcut=src)]
+            c = width
+    layers += [global_pool(), fc(c, n_classes)]
+    return CutieGraph(
+        name=name,
+        layers=tuple(layers),
+        input_hw=input_hw,
+        input_ch=3,
+        n_classes=n_classes,
+    )
+
+
 register_net("cifar10_tnn", cifar10_tnn_graph)
 register_net("dvs_cnn_tcn", dvs_cnn_tcn_graph)
 register_net("cifar10_tnn_wide", cifar10_tnn_wide_graph)
 register_net("kws_tcn", kws_tcn_graph)
+register_net("resnet20_tnn", resnet20_tnn_graph)
 # legacy config names from configs/cutie_nets.py
 register_net("cutie_cifar10", cifar10_tnn_graph)
 register_net("cutie_dvs", dvs_cnn_tcn_graph)
@@ -280,5 +321,11 @@ register_net(
     lambda: dvs_cnn_tcn_graph(
         channels=6, n_classes=6, input_hw=(32, 32), tcn_steps=4,
         name="dvs_cnn_tcn_nano",
+    ),
+)
+register_net(
+    "resnet20_tnn_smoke",
+    lambda: resnet20_tnn_graph(
+        widths=(4, 8, 16), input_hw=(16, 16), name="resnet20_tnn_smoke"
     ),
 )

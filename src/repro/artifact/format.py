@@ -11,7 +11,7 @@ On-disk layout (all integers little-endian; spec in docs/artifact.md):
 
     offset  size  field
     0       8     magic            b"CUTIEPRG"
-    8       2     version (u16)    container format version, currently 2
+    8       2     version (u16)    container format version, currently 3
     10      2     flags (u16)      reserved, 0
     12      4     payload_len (u32)
     16      4     crc32 (u32)      zlib CRC-32 over the payload bytes
@@ -37,7 +37,9 @@ instead of guessing.  Additive metadata goes into META/image-header JSON
 keys (old readers must ignore unknown keys); structural changes bump.
 Version history: v1 original; v2 adds the per-layer ``stride`` key to the
 PLAN section (strided convs) — v2 readers still accept v1 payloads
-(missing ``stride`` deserializes to 1), so `MIN_VERSION` stays 1.
+(missing ``stride`` deserializes to 1), so `MIN_VERSION` stays 1; v3 adds
+the per-layer ``shortcut`` key (the residual's source layer, null for
+none) — a missing key deserializes to none, so v1 and v2 files still load.
 """
 from __future__ import annotations
 
@@ -50,7 +52,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 MAGIC = b"CUTIEPRG"
-VERSION = 2      # written; bumped when the payload layout changes
+VERSION = 3      # written; bumped when the payload layout changes
 MIN_VERSION = 1  # oldest payload this reader still understands
 HEADER = struct.Struct("<8sHHII")  # magic, version, flags, payload_len, crc32
 _U32 = struct.Struct("<I")

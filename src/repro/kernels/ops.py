@@ -38,6 +38,7 @@ from repro.kernels.ternary_matmul import (
 from repro.kernels.ternary_conv2d import (
     ternary_conv2d_native,
     ternary_conv2d_pallas,
+    ternary_conv2d_residual_pallas,
 )
 
 IMPLS = ("native", "pallas", "interpret")
@@ -148,6 +149,7 @@ def ternary_conv2d(
     fuse_ternary: bool = False,
     threshold=0.5,
     fuse_pool: int = 0,
+    residual=None,
     interpret: bool | None = None,
     impl: str | None = None,
     out_dtype=None,
@@ -162,7 +164,12 @@ def ternary_conv2d(
     ``block_cout``: the Pallas output-channel block.  ``None`` means "no
     plan spoke": 128, clamped to C_out (plan-driven callers pass each
     layer's `kernels.autotune` block).  Ragged C_out is padded up to the
-    block and sliced back out, fused epilogue included."""
+    block and sliced back out, fused epilogue included.
+
+    ``residual``: a shortcut [B, H, W, C_out] at the conv's output size,
+    added to the scaled accumulator before the threshold (and any pool).
+    On the Pallas path it launches `ternary_conv2d_residual_pallas`;
+    without it the plain kernel launches with its four operands."""
     impl = _resolve_impl(impl, interpret)
     kh, kw, c4, c_out = w_packed.shape
     c_in = x.shape[-1]
@@ -175,16 +182,20 @@ def ternary_conv2d(
         raise ValueError(f"threshold shape {thr.shape} != ({c_out},)")
     if impl == "native":
         return ternary_conv2d_native(
-            x, w_packed, scale.reshape(-1), thr, fuse_ternary=fuse_ternary,
-            fuse_pool=fuse_pool, out_dtype=out_dtype or x.dtype,
+            x, w_packed, scale.reshape(-1), thr, residual,
+            fuse_ternary=fuse_ternary, fuse_pool=fuse_pool,
+            out_dtype=out_dtype or x.dtype,
         )
     bc = min(block_cout or 128, c_out)
     wp = _pad_to(w_packed, 3, bc)
     sc = _pad_to(scale.reshape(-1), 0, bc)
     th = _pad_to(thr, 0, bc)
-    y = ternary_conv2d_pallas(
-        x, wp, sc, th, block_cout=bc, fuse_ternary=fuse_ternary,
-        fuse_pool=fuse_pool, interpret=_interpret_flag(impl, interpret),
-        out_dtype=out_dtype or x.dtype,
-    )
+    launch = dict(block_cout=bc, fuse_ternary=fuse_ternary, fuse_pool=fuse_pool,
+               interpret=_interpret_flag(impl, interpret),
+               out_dtype=out_dtype or x.dtype)
+    if residual is None:
+        y = ternary_conv2d_pallas(x, wp, sc, th, **launch)
+    else:
+        y = ternary_conv2d_residual_pallas(
+            x, wp, sc, th, _pad_to(residual, 3, bc), **launch)
     return y[..., :c_out]

@@ -29,9 +29,11 @@ def ternary_conv2d_ref(
     fuse_ternary: bool = False,
     threshold=0.5,
     fuse_pool: int = 0,
+    residual=None,
     out_dtype=None,
 ) -> jax.Array:
     """SAME conv with ternary packed weights [KH,KW,C_in/4,C_out] + scale.
+    ``residual`` [B, H, W, C_out] is added to the scaled accumulator;
     ``threshold`` is a scalar or per-channel [C_out] vector (broadcast over
     pixels); ``fuse_pool`` > 1 appends a window/stride ``fuse_pool``
     max-pool after the optional ternarization — the oracle for the fused
@@ -45,6 +47,8 @@ def ternary_conv2d_ref(
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         precision=jax.lax.Precision.HIGHEST,
     ) * scale.reshape(1, 1, 1, -1).astype(jnp.float32)
+    if residual is not None:
+        y = y + residual.astype(jnp.float32)
     if fuse_ternary:
         y = jnp.where(jnp.abs(y) > jnp.asarray(threshold, jnp.float32), jnp.sign(y), 0.0)
     if fuse_pool > 1:

@@ -23,8 +23,12 @@ The fused epilogue optionally applies CUTIE's activation ternarization
 (sign/threshold) and the layer's 2x2 max-pool, which the silicon folds into
 the OCU pipeline after the adder tree (ThFU + pooling unit) — so a whole TNN
 layer, pooling included, is a single launch whose output is the int8
-ternary activation map.  The wide float accumulator never leaves the kernel:
-inter-layer traffic is exactly the silicon's 2-bit activation memory model.
+ternary activation map.  A residual layer adds its shortcut's trits (an int8
+operand at the conv's output size, blocked like the output) to the scaled
+accumulator before the threshold; such a launch is its own jitted entry,
+``ternary_conv2d_residual_pallas``, so the device trace names it apart.
+The wide float accumulator never leaves the kernel: inter-layer traffic is
+exactly the silicon's 2-bit activation memory model.
 
 Two implementations share the decode + tap walk + epilogue semantics:
 
@@ -54,11 +58,14 @@ from repro.kernels.ternary_matmul import select_tile
 
 
 def _epilogue(y, scale, thr, *, h: int, w: int, bn: int,
-              fuse_ternary: bool, fuse_pool: int):
-    """Scale -> optional ThFU ternarize -> optional epilogue max-pool, on a
-    (pixels, bn) accumulator (pixels row-major over (h, w)).  Shared by the
+              fuse_ternary: bool, fuse_pool: int, res=None):
+    """Scale -> optional residual add -> optional ThFU ternarize -> optional
+    epilogue max-pool, on a (pixels, bn) accumulator (pixels row-major over
+    (h, w)); ``res`` is the shortcut as f32 (pixels, bn).  Shared by the
     Pallas kernel body and the native path — one semantics definition."""
     y = y * scale.astype(jnp.float32)
+    if res is not None:
+        y = y + res
     if fuse_ternary:
         # ThFU: per-OCU comparator constants — a (1, bn) threshold row
         # broadcast over the pixels (scalar thresholds arrive pre-splatted)
@@ -73,10 +80,13 @@ def _epilogue(y, scale, thr, *, h: int, w: int, bn: int,
 
 
 def _tconv_kernel(
-    x_ref, wp_ref, scale_ref, thr_ref, o_ref, acc_ref, *, h: int, w: int,
-    kh: int, kw: int, fuse_ternary: bool, fuse_pool: int,
+    x_ref, wp_ref, scale_ref, thr_ref, *refs, h: int, w: int,
+    kh: int, kw: int, fuse_ternary: bool, fuse_pool: int, residual: bool,
 ):
-    """One (sample, output-channel-tile) grid cell: full-image conv."""
+    """One (sample, output-channel-tile) grid cell: full-image conv.
+    ``refs`` is ``(res_ref, o_ref, acc_ref)`` with a residual operand,
+    ``(o_ref, acc_ref)`` without."""
+    o_ref, acc_ref = refs[-2:]
     c_in = x_ref.shape[-1]
     bn = o_ref.shape[-1]
     # [KH, KW, C4, bn] uint8 -> [KH, KW, C_in, bn]: the packed C_in axis is
@@ -97,11 +107,23 @@ def _tconv_kernel(
                 preferred_element_type=jnp.float32,
             )
 
+    res = None
+    if residual:
+        # widened before the merge, as the window is
+        res = refs[0][0].astype(jnp.float32).reshape(h * w, bn)
     y = _epilogue(
         acc_ref[...], scale_ref[...], thr_ref[...], h=h, w=w, bn=bn,
-        fuse_ternary=fuse_ternary, fuse_pool=fuse_pool,
+        fuse_ternary=fuse_ternary, fuse_pool=fuse_pool, res=res,
     )
     o_ref[...] = y[None].astype(o_ref.dtype)
+
+
+def _check_residual(residual, b, h, w, c_out):
+    if residual.shape != (b, h, w, c_out):
+        raise ValueError(
+            f"residual shape {residual.shape} != the conv output "
+            f"{(b, h, w, c_out)} (the shortcut is added before any pool)"
+        )
 
 
 def _check_geometry(c_in, c4, h, w, fuse_pool):
@@ -117,12 +139,59 @@ def _check_geometry(c_in, c4, h, w, fuse_pool):
         )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "block_cout", "interpret", "fuse_ternary", "fuse_pool", "out_dtype"
-    ),
-)
+def _conv2d_pallas(x, w_packed, scale, threshold, residual, *, block_cout,
+                   fuse_ternary, fuse_pool, interpret, out_dtype, name=None):
+    """The one `pallas_call` behind both jitted entries; ``residual`` None
+    launches the plain kernel with exactly its four operands."""
+    b, h, w, c_in = x.shape
+    kh, kw, c4, c_out = w_packed.shape
+    _check_geometry(c_in, c4, h, w, fuse_pool)
+    if not 0 < block_cout <= c_out or c_out % block_cout:
+        raise ValueError(
+            f"block_cout={block_cout} cannot tile C_out={c_out}: it must "
+            "divide C_out (kernels.ops.ternary_conv2d pads ragged C_out to "
+            "a block multiple; kernels.autotune only emits dividing blocks)"
+        )
+    out_dtype = out_dtype or x.dtype
+    ph, pw = kh // 2, kw // 2
+    xp = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw), (0, 0)))
+    scale = scale.reshape(1, c_out)
+    thr = threshold.reshape(1, c_out)
+    oh, ow = (h // fuse_pool, w // fuse_pool) if fuse_pool > 1 else (h, w)
+
+    kern = functools.partial(
+        _tconv_kernel, h=h, w=w, kh=kh, kw=kw,
+        fuse_ternary=fuse_ternary, fuse_pool=fuse_pool,
+        residual=residual is not None,
+    )
+    in_specs = [
+        pl.BlockSpec((1, h + kh - 1, w + kw - 1, c_in), lambda i, j: (i, 0, 0, 0)),
+        pl.BlockSpec((kh, kw, c4, block_cout), lambda i, j: (0, 0, 0, j)),
+        pl.BlockSpec((1, block_cout), lambda i, j: (0, j)),
+        pl.BlockSpec((1, block_cout), lambda i, j: (0, j)),
+    ]
+    operands = [xp, w_packed, scale, thr]
+    if residual is not None:
+        _check_residual(residual, b, h, w, c_out)
+        in_specs.append(
+            pl.BlockSpec((1, h, w, block_cout), lambda i, j: (i, 0, 0, j)))
+        operands.append(residual)
+    return pl.pallas_call(
+        kern,
+        grid=(b, c_out // block_cout),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, oh, ow, block_cout), lambda i, j: (i, 0, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, oh, ow, c_out), out_dtype),
+        scratch_shapes=[pltpu.VMEM((h * w, block_cout), jnp.float32)],
+        interpret=interpret,
+        name=name,
+    )(*operands)
+
+
+_PALLAS_STATIC = ("block_cout", "interpret", "fuse_ternary", "fuse_pool", "out_dtype")
+
+
+@functools.partial(jax.jit, static_argnames=_PALLAS_STATIC)
 def ternary_conv2d_pallas(
     x: jax.Array,
     w_packed: jax.Array,
@@ -145,40 +214,37 @@ def ternary_conv2d_pallas(
     a window/stride ``fuse_pool`` max-pool to the epilogue (after the
     optional ternarization), shrinking the output to [B, H/p, W/p, C_out].
     The kernel compiles for TPU; only ``interpret=True`` runs it elsewhere."""
-    b, h, w, c_in = x.shape
-    kh, kw, c4, c_out = w_packed.shape
-    _check_geometry(c_in, c4, h, w, fuse_pool)
-    if not 0 < block_cout <= c_out or c_out % block_cout:
-        raise ValueError(
-            f"block_cout={block_cout} cannot tile C_out={c_out}: it must "
-            "divide C_out (kernels.ops.ternary_conv2d pads ragged C_out to "
-            "a block multiple; kernels.autotune only emits dividing blocks)"
-        )
-    out_dtype = out_dtype or x.dtype
-    ph, pw = kh // 2, kw // 2
-    xp = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw), (0, 0)))
-    scale = scale.reshape(1, c_out)
-    thr = threshold.reshape(1, c_out)
-    oh, ow = (h // fuse_pool, w // fuse_pool) if fuse_pool > 1 else (h, w)
-
-    kern = functools.partial(
-        _tconv_kernel, h=h, w=w, kh=kh, kw=kw,
-        fuse_ternary=fuse_ternary, fuse_pool=fuse_pool,
+    return _conv2d_pallas(
+        x, w_packed, scale, threshold, None, block_cout=block_cout,
+        fuse_ternary=fuse_ternary, fuse_pool=fuse_pool, interpret=interpret,
+        out_dtype=out_dtype,
     )
-    return pl.pallas_call(
-        kern,
-        grid=(b, c_out // block_cout),
-        in_specs=[
-            pl.BlockSpec((1, h + kh - 1, w + kw - 1, c_in), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, c4, block_cout), lambda i, j: (0, 0, 0, j)),
-            pl.BlockSpec((1, block_cout), lambda i, j: (0, j)),
-            pl.BlockSpec((1, block_cout), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, oh, ow, block_cout), lambda i, j: (i, 0, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((b, oh, ow, c_out), out_dtype),
-        scratch_shapes=[pltpu.VMEM((h * w, block_cout), jnp.float32)],
-        interpret=interpret,
-    )(xp, w_packed, scale, thr)
+
+
+@functools.partial(jax.jit, static_argnames=_PALLAS_STATIC)
+def ternary_conv2d_residual_pallas(
+    x: jax.Array,
+    w_packed: jax.Array,
+    scale: jax.Array,
+    threshold: jax.Array,
+    residual: jax.Array,
+    *,
+    block_cout: int = 128,
+    fuse_ternary: bool = False,
+    fuse_pool: int = 0,
+    interpret: bool = False,
+    out_dtype=None,
+):
+    """`ternary_conv2d_pallas` with a shortcut: ``residual`` [B, H, W,
+    C_out] (int8 trits on the fused path), at the conv's output size and
+    blocked like the output, is added to the scaled accumulator before the
+    threshold (and before any epilogue pool).  Its launches carry this
+    name in the device trace."""
+    return _conv2d_pallas(
+        x, w_packed, scale, threshold, residual, block_cout=block_cout,
+        fuse_ternary=fuse_ternary, fuse_pool=fuse_pool, interpret=interpret,
+        out_dtype=out_dtype, name="ternary_conv2d_residual_pallas",
+    )
 
 
 @functools.partial(
@@ -190,6 +256,7 @@ def ternary_conv2d_native(
     w_packed: jax.Array,
     scale: jax.Array,
     threshold: jax.Array,
+    residual=None,
     *,
     fuse_ternary: bool = False,
     fuse_pool: int = 0,
@@ -201,7 +268,8 @@ def ternary_conv2d_native(
     [C_in, C_out] dot per tap instead of one grid cell per sample).  This is
     the CPU-native packed path `ops.ternary_conv2d` dispatches when no
     Pallas machinery is requested; there is no block tiling because XLA
-    tiles the dots itself."""
+    tiles the dots itself.  ``residual`` [B, H, W, C_out] is the shortcut,
+    added before the threshold as in the residual kernel."""
     b, h, w, c_in = x.shape
     kh, kw, c4, c_out = w_packed.shape
     _check_geometry(c_in, c4, h, w, fuse_pool)
@@ -224,10 +292,14 @@ def ternary_conv2d_native(
     # batch rides as extra leading pixel rows: run the shared epilogue with
     # h' = b*h (row-major layout makes the pool grouping identical per
     # sample as long as fuse_pool divides h, which _check_geometry ensured)
+    res = None
+    if residual is not None:
+        _check_residual(residual, b, h, w, c_out)
+        res = residual.astype(jnp.float32).reshape(b * h * w, c_out)
     y = _epilogue(
         acc, scale.reshape(1, c_out), jnp.reshape(threshold, (1, c_out)),
         h=b * h, w=w, bn=c_out, fuse_ternary=fuse_ternary,
-        fuse_pool=fuse_pool,
+        fuse_pool=fuse_pool, res=res,
     )
     oh, ow = (h // fuse_pool, w // fuse_pool) if fuse_pool > 1 else (h, w)
     return y.reshape(b, oh, ow, c_out).astype(out_dtype)

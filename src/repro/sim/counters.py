@@ -20,10 +20,12 @@ with one closed formula, this module walks the plan's schedule:
     (`SimParams.pipeline_drain_cycles`);
   * ``bank_conflict_stalls`` / ``ndb_stalls`` — feature-memory serialization
     when a layer's maps spill one bank and double buffering breaks
-    (`FeatureMemory.layer_stalls`).  Zero for every registry net on the
-    Kraken bank geometry — the silicon was sized so they never fire — but
-    the counters make the golden model honest about programs that spill
-    (tests force them with a shrunken ``SimParams.fmap_bank_bytes``).
+    (`FeatureMemory.layer_stalls`), with a live shortcut map counted
+    beside the input map (`FeatureMemory.resident_bytes`).  Zero for
+    every registry net on the Kraken bank geometry — the silicon was sized
+    so they never fire — but the counters make the golden model honest
+    about programs that spill (tests force them with a shrunken
+    ``SimParams.fmap_bank_bytes``).
 
 For every 3x3 network the non-stall terms reduce to the analytic formula,
 so sim and analytic cycles reconcile to within the drain overhead — the
@@ -151,12 +153,13 @@ def count_plan(
     hw = hw or arch.CutieHW()
     params = params or SimParams()
     fmem = FeatureMemory(max_cin=hw.max_cin, bank_bytes=params.fmap_bank_bytes)
+    resident = fmem.resident_bytes(plan)
     out: List[LayerCounters] = []
     for lp in plan.layers:
         cycles = _layer_cycles(lp, hw, params)
         traffic = fmem.layer_traffic(lp)
-        stalls = (fmem.layer_stalls(lp) if params.count_stalls
-                  else {"bank_conflict": 0, "ndb": 0})
+        stalls = (fmem.layer_stalls(lp, resident.get(lp.index, 0))
+                  if params.count_stalls else {"bank_conflict": 0, "ndb": 0})
         cycles += stalls["bank_conflict"] + stalls["ndb"]
         util = (lp.macs / (cycles * hw.ops_per_cycle / 2)) if cycles else 0.0
         w_sparsity = 0.0

@@ -143,18 +143,22 @@ class PlanExecutor:
         y = cout_groups[0] if len(cout_groups) == 1 else jnp.concatenate(cout_groups, -1)
         return y * jnp.asarray(img.eff_scale).reshape(1, 1, 1, -1)
 
-    def _conv_layer(self, x: jax.Array, lp: LayerPlan) -> jax.Array:
+    def _conv_layer(self, x: jax.Array, lp: LayerPlan, res=None) -> jax.Array:
+        """One conv layer; ``res`` is its shortcut map at its output size
+        (`api.program.shortcut_map`), added before the threshold."""
         from repro.api.program import _dispatch_conv
 
         img = self.memory.image_for(lp)
         x = _pad_channels(x, lp.c_pad)
         if self.backend == "bitsim":
             y = self._tiled_conv(x, lp, img)
+            if res is not None:
+                y = y + res.astype(jnp.float32)
         elif self.backend == "fused":
             t = _dispatch_conv(
                 x, jnp.asarray(img.packed), jnp.asarray(img.eff_scale),
                 "fused", threshold=img.threshold, pool=lp.pool,
-                block_cout=self._block_cout(lp),
+                block_cout=self._block_cout(lp), residual=res,
             )
             if lp.stride > 1:
                 t = t[:, :: lp.stride, :: lp.stride, :]
@@ -162,7 +166,7 @@ class PlanExecutor:
         else:
             y = _dispatch_conv(
                 x, jnp.asarray(img.packed), jnp.asarray(img.eff_scale),
-                self.backend, block_cout=self._block_cout(lp),
+                self.backend, block_cout=self._block_cout(lp), residual=res,
             )
         t = _ternarize(y, img.threshold)
         if lp.stride > 1:
@@ -218,10 +222,20 @@ class PlanExecutor:
     # -- program-level forwards -------------------------------------------
 
     def spatial_forward(self, x: jax.Array) -> jax.Array:
-        """Frontend (or whole spatial net): [B, H, W, C] -> features/logits."""
+        """Frontend (or whole spatial net): [B, H, W, C] -> features/logits.
+        A shortcut source's output stays live until the layer that adds it."""
+        from repro.api.program import shortcut_map
+
+        sources, saved = self.plan.shortcut_sources, {}
         for lp in self.plan.spatial_layers:
             if lp.kind == "conv2d":
-                x = self._conv_layer(x, lp)
+                res = None
+                if lp.shortcut is not None:
+                    res = shortcut_map(saved.pop(lp.shortcut),
+                                       (*x.shape[:3], lp.c_out))
+                x = self._conv_layer(x, lp, res)
+                if lp.index in sources:
+                    saved[lp.index] = x
             elif lp.kind == "pool":
                 x = _max_pool(x, lp.pool)
             elif lp.kind == "global_pool":

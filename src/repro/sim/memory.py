@@ -11,7 +11,10 @@ per-channel vector, exactly what the fused kernel epilogue receives.
 `FeatureMemory` models the double-buffered activation memories: two banks of
 2-bit activation words; layer N reads its input map from one bank while
 writing its output to the other, so there is no structural stall — the cost
-is the *traffic*, which `sim.counters` reports per layer.
+is the *traffic*, which `sim.counters` reports per layer.  A residual
+shortcut keeps a second map live: its source's output stays resident from
+the layer after the one that reads it as input up to its consumer, and the
+consumer reads it once more (`FeatureMemory.resident_bytes`).
 
 `RingBufferSchedule` is the 24-step TCN ring (the 576 B SCM shift register):
 one push per frontend pass, a full ordered-window read per TCN-head layer.
@@ -217,7 +220,11 @@ class FeatureMemory:
     bank; a layer that spills shares a bank between the in-flight read
     stream and the writeback, which `layer_stalls` prices (the sim's
     bank-conflict / non-double-bufferable counters — zero for every
-    registry net on the Kraken geometry)."""
+    registry net on the Kraken geometry).
+
+    A saved shortcut map that a layer does not stream as its input sits in
+    the bank beside that layer's input map (``resident`` bytes, from
+    `resident_bytes`): the layer double-buffers only if both fit."""
 
     max_cin: int
     bank_bytes: int = KRAKEN_FMAP_BANK_BYTES
@@ -229,16 +236,34 @@ class FeatureMemory:
             return lp.h // lp.pool, lp.w // lp.pool
         return lp.h, lp.w
 
-    def double_bufferable(self, lp: LayerPlan) -> bool:
-        """True when layer ``lp``'s in and out maps each fit one bank.
-        Non-conv layers are addressing-only and trivially double-buffer."""
+    def resident_bytes(self, plan: ExecutionPlan) -> Dict[int, int]:
+        """Per plan-layer ``index``: bytes of saved shortcut maps resident
+        beside its input map.  A source's output map is resident at every
+        layer after the one that streams it as input, up to and including
+        the shortcut's consumer (which reads it once more)."""
+        out: Dict[int, int] = {}
+        pos = {lp.index: n for n, lp in enumerate(plan.layers)}
+        for lp in plan.layers:
+            if lp.shortcut is None:
+                continue
+            src = plan.layers[pos[lp.shortcut]]
+            oh, ow = self.out_hw(src)
+            nbytes = fmap_bytes(oh, ow, src.c_out)
+            for mid in plan.layers[pos[lp.shortcut] + 2 : pos[lp.index] + 1]:
+                out[mid.index] = out.get(mid.index, 0) + nbytes
+        return out
+
+    def double_bufferable(self, lp: LayerPlan, resident: int = 0) -> bool:
+        """True when layer ``lp``'s in map (with ``resident`` saved-map bytes
+        beside it) and its out map each fit one bank.  Non-conv layers are
+        addressing-only and trivially double-buffer."""
         if lp.kind not in ("conv2d", "tcn"):
             return True
         oh, ow = self.out_hw(lp)
-        return (fmap_bytes(lp.h, lp.w, lp.c_in) <= self.bank_bytes
+        return (fmap_bytes(lp.h, lp.w, lp.c_in) + resident <= self.bank_bytes
                 and fmap_bytes(oh, ow, lp.c_out) <= self.bank_bytes)
 
-    def layer_stalls(self, lp: LayerPlan) -> dict:
+    def layer_stalls(self, lp: LayerPlan, resident: int = 0) -> dict:
         """{bank_conflict, ndb} stall cycles for one plan layer.
 
         Double-bufferable layers stall zero cycles — ping-pong banking
@@ -253,7 +278,7 @@ class FeatureMemory:
             pass's writeback burst: one extra (kh-1)-row fill per tile
             pass on top of the pipelined fill the cycle model already
             counts."""
-        if lp.kind not in ("conv2d", "tcn") or self.double_bufferable(lp):
+        if lp.kind not in ("conv2d", "tcn") or self.double_bufferable(lp, resident):
             return {"bank_conflict": 0, "ndb": 0}
         traffic = self.layer_traffic(lp)
         fill = (lp.kh - 1) * lp.w
@@ -267,14 +292,17 @@ class FeatureMemory:
 
         conv/tcn: every tile pass streams the input map once through the
         line buffer (h*w words per tile), and each cout-tile group writes
-        the (post-pool) output map once.  Pool/global_pool/flatten are
-        addressing-only on the read side; fc reads its input vector once
-        and writes the logits."""
+        the (post-pool) output map once — and, with a shortcut, reads its
+        slice of the saved map once per output pixel.  Pool/global_pool/
+        flatten are addressing-only on the read side; fc reads its input
+        vector once and writes the logits."""
         if lp.kind in ("conv2d", "tcn"):
             n_tiles = max(len(lp.tiles), 1)
             cout_groups = len({(t.cout_lo, t.cout_hi) for t in lp.tiles}) or 1
             out_pix = lp.out_pixels // (lp.pool * lp.pool) if lp.pool else lp.out_pixels
-            return {"reads": n_tiles * lp.h * lp.w, "writes": cout_groups * out_pix}
+            shortcut = cout_groups * out_pix if lp.shortcut is not None else 0
+            return {"reads": n_tiles * lp.h * lp.w + shortcut,
+                    "writes": cout_groups * out_pix}
         if lp.kind in ("pool", "global_pool"):
             return {"reads": lp.h * lp.w, "writes": 1 if lp.kind == "global_pool"
                     else (lp.h // lp.pool) * (lp.w // lp.pool)}

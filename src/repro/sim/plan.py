@@ -16,7 +16,9 @@ the slice of the trit-packed weight image it consumes.
 
 A conv layer immediately followed by a ``pool`` absorbs it (``pool`` field),
 mirroring the silicon's in-pipeline pooling unit and the fused deploy
-backend (`CutieGraph.conv_pool_plan`).
+backend (`CutieGraph.conv_pool_plan`).  A residual conv carries its
+``shortcut`` source, so the plan alone (an artifact's) executes and prices
+the second live map.
 
 Plans serialize losslessly (`to_dict`/`from_dict`) — the round trip is
 pinned in tests/test_sim.py.
@@ -66,7 +68,9 @@ class LayerPlan:
                   pre-stride); ``pool`` > 0 is the absorbed epilogue max-pool
                   window; ``stride`` > 1 subsamples the ternarized output
                   (the schedule prices only the kept output pixels — a
-                  strided conv never absorbs a pool).
+                  strided conv never absorbs a pool); ``shortcut`` is the
+                  ``index`` of the conv whose output this layer adds
+                  before its threshold (None: a plain chain step).
       * tcn:      ``h`` = ceil(tcn_steps / dilation) rows, ``w`` = dilation
                   columns — the §4 wrapped form the 2-D engine runs.
       * fc:       ``c_in`` is the matmul fan-in (flattened features);
@@ -88,6 +92,7 @@ class LayerPlan:
     c_pad: int = 0
     arch_c_in: int = 0
     stride: int = 1
+    shortcut: Optional[int] = None
     tiles: Tuple[TileAssign, ...] = ()
 
     @property
@@ -160,6 +165,13 @@ class ExecutionPlan:
 
     def weight_layers(self) -> List[LayerPlan]:
         return [lp for lp in self.layers if lp.kind in ("conv2d", "tcn", "fc")]
+
+    @property
+    def shortcut_sources(self) -> Tuple[int, ...]:
+        """``index`` of every layer whose output a later shortcut reads —
+        the maps an executor keeps live (`CutieGraph.shortcut_sources`)."""
+        return tuple(sorted({lp.shortcut for lp in self.layers
+                             if lp.shortcut is not None}))
 
     # -- the analytic model's layer list (export_conv_layers) --------------
 
@@ -234,7 +246,7 @@ def lower(graph: CutieGraph, hw: Optional[arch.CutieHW] = None) -> ExecutionPlan
             layers.append(LayerPlan(
                 index=i, kind="conv2d", h=h, w=w, c_in=l.c_in, c_out=l.c_out,
                 kh=l.kernel[0], kw=l.kernel[1], pool=fused_pool, c_pad=c_pad,
-                stride=l.stride,
+                stride=l.stride, shortcut=l.shortcut,
                 tiles=_tile_ranges(l.c_out, c_pad, hw.n_ocu, hw.max_cin),
             ))
             c = l.c_out

@@ -191,9 +191,10 @@ class TestRoundTrip:
 # library-version-dependent float anywhere — trits are (arange % 3) - 1 and
 # scales are small-integer/8 (exact in float32).  If this pin moves, the
 # on-disk format changed: bump VERSION and docs/artifact.md.
-# Pin history: v1 7b1673af...390c; v2 (PLAN layers carry "stride"):
+# Pin history: v1 7b1673af...390c; v2 (PLAN layers carry "stride")
+# d0116d48...7199; v3 (PLAN layers carry "shortcut"):
 _HAND_BUILT_SHA256 = (
-    "d0116d48965da975b6acbb5a35608390d8281c876bf459c7ca54b3a46a917199"
+    "67d5c8906d2fa496c8ccdbc40f609e1ecccdd9123d1d005e29ea47482d103b8e"
 )
 
 

@@ -21,7 +21,10 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.kernels.ternary_conv2d import ternary_conv2d_pallas
+from repro.kernels.ternary_conv2d import (
+    ternary_conv2d_pallas,
+    ternary_conv2d_residual_pallas,
+)
 from repro.kernels.ternary_matmul import ternary_matmul_pallas
 
 
@@ -87,6 +90,55 @@ def test_conv_kernel_compiles_for_v5e(one_chip, case):
     x_shape, w_shape, pool, block, x_dtype = CONV_CASES[case]
     hlo = _compile_conv(one_chip, x_shape, w_shape, pool, block, x_dtype)
     assert "tpu_custom_call" in hlo
+
+
+# resnet20_tnn's shortcut convs: (H = W, C) of each stage
+RESIDUAL_CASES = {"32x32x16": (32, 16), "16x16x32": (16, 32), "8x8x64": (8, 64)}
+
+
+@pytest.mark.parametrize("case", list(RESIDUAL_CASES))
+def test_residual_conv_kernel_compiles_for_v5e(one_chip, case):
+    """The residual epilogue (int8 shortcut operand, blocked like the
+    output) at each ResNet-20 stage, under its own launch name."""
+    hw, c = RESIDUAL_CASES[case]
+    lowered = ternary_conv2d_residual_pallas.lower(
+        _spec((2, hw, hw, c), jnp.int8, one_chip),
+        _spec((3, 3, c // 4, c), jnp.uint8, one_chip),
+        _spec((c,), jnp.float32, one_chip), _spec((c,), jnp.float32, one_chip),
+        _spec((2, hw, hw, c), jnp.int8, one_chip),
+        block_cout=c, fuse_ternary=True, interpret=False, out_dtype=jnp.int8,
+    )
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert "%ternary_conv2d_residual_pallas" in hlo
+
+
+def _operands(hlo_line: str) -> int:
+    return hlo_line.split("custom-call(", 1)[1].split(")", 1)[0].count("%")
+
+
+def test_resnet20_step_launches_plain_and_residual_kernels(one_chip, monkeypatch):
+    """resnet20_tnn's fused forward: its 10 plain convs launch the plain
+    kernel under its own name with its four operands, its 9 shortcut convs
+    the residual kernel with the shortcut as a fifth."""
+    import re
+
+    import repro.kernels.ops as ops
+    from repro import api
+
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    prog = api.get_net("resnet20_tnn")
+    dep = prog.quantize(prog.init(jax.random.PRNGKey(0)))
+    fwd = jax.jit(lambda x: dep.forward(x, backend="fused"))
+    hlo = fwd.lower(_spec((3, 32, 32, 3), jnp.float32, one_chip)).compile().as_text()
+    calls = [l for l in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    plain = [l for l in calls if re.search(r"%ternary_conv2d_pallas(\.\d+)? = ", l)]
+    residual = [l for l in calls
+                if re.search(r"%ternary_conv2d_residual_pallas(\.\d+)? = ", l)]
+    assert (len(plain), len(residual), len(calls)) == (10, 9, 19)
+    assert {_operands(l) for l in plain} == {4}
+    assert {_operands(l) for l in residual} == {5}
 
 
 def test_wide_192_layer_compiles_with_autotuned_block(one_chip):
