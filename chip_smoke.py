@@ -128,7 +128,8 @@ def pool_lowering(pool):
     import jax.numpy as jnp
 
     batch, active = pool.prepare({})
-    return pool._step.lower(pool.state, jnp.asarray(batch), jnp.asarray(active))
+    lanes = active.astype(np.int8)  # the lane code `step_prepared` sends
+    return pool._step.lower(pool.state, jnp.asarray(batch), jnp.asarray(lanes))
 
 
 def report_ticks(what: str, tick_s) -> None:

@@ -19,14 +19,18 @@ The serving tick (`ContinuousBatcher.tick`), on the batcher's track:
     tick                      one scheduling round (arg ``tick``)
       gate.park, gate.scan    activity-gate bookkeeping (gated batchers)
       admit                   FIFO slot refill
-        pool.admit            `SessionPool.admit`: clear/scatter the slot (``slot``)
+        pool.admit            `SessionPool.admit`: mark the slot fresh, or
+                              scatter a given state into it (``slot``)
       assemble                host batch assembly
       step                    dispatch of the jitted step (``streams``)
-        pool.step             the jitted call (``pool_size``)
+        pool.step             the jitted call (``pool_size``; ``fresh``:
+                              lanes it zeroes before the push)
       demux                   one host copy of the step's logits and its
                               per-stream rows; waits for the step (``streams``)
-      retire                  cursors, evictions, results (``departed``)
-        pool.evict            `SessionPool.evict`: gather the slot (``slot``)
+      retire                  cursors, departures, results (``departed``)
+        pool.evict            `SessionPool.release`: free the slot unread
+                              (``slot``, ``gathered`` 0); `.evict` gathers
+                              it (``gathered`` 1; gate.park, pool swaps)
     sched (counter)           one per non-idle tick, at its end: ``gc_ms``,
                               ``runq_ms`` of the ticking thread
 

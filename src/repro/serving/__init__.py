@@ -25,7 +25,7 @@ Layering: `masking` (pure state algebra) <- `pool` (mechanism) <-
 
 from repro.serving.masking import (
     PoolState,
-    clear_slot,
+    clear_lanes,
     gather_slot,
     masked_push,
     ordered_windows,
@@ -62,7 +62,7 @@ __all__ = [
     "bucket_ladder",
     "serve_fleet",
     "PoolState",
-    "clear_slot",
+    "clear_lanes",
     "gather_slot",
     "masked_push",
     "ordered_windows",

@@ -9,26 +9,36 @@ own monotonic step counter: a stream admitted into slot 3 while slot 0 is
 
 Everything here is functionally pure and shape-stable, so the pool's step
 traces **once** per (pool_size, backend) and admission/eviction/masking are
-runtime data (`active` is a traced argument, never a static one) — that is
-the no-retrace property continuous batching needs.
+runtime data — that is the no-retrace property continuous batching needs.
+The step takes one `[P]` lane code as a traced argument, never a static
+one: bit 0 (`STEP`) pushes the lane's frame, bit 1 (`FRESH`) zeroes the
+lane's ring and counters first (`clear_lanes`).  A plain bool mask is the
+code with bit 1 unset.  So a cold admission or a reset is data of the next
+step, not a device operation of its own, and a departing stream whose state
+nobody reads is released without touching the device.
 
-Slot surgery (`gather_slot` / `scatter_slot` / `clear_slot`) converts
-between the pooled state and the single-stream `StreamState` pytree that
-`StreamSession` exposes, which is what makes sessions migratable: evict a
-stream from one pool and admit its state into another (or into a standalone
-session) with bit-identical logits from then on (tested in
-tests/test_serving.py).
+Slot surgery (`gather_slot` / `scatter_slot`) stays host-side and eager: it
+converts between the pooled state and the single-stream `StreamState`
+pytree that `StreamSession` exposes, which is what makes sessions
+migratable: evict a stream from one pool and admit its state into another
+(or into a standalone session) with bit-identical logits from then on
+(tested in tests/test_serving.py).
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from repro.core.tcn import StreamState, TCNStream
+
+# bits of the step's per-lane code
+STEP = 1  # push this tick's frame into the lane
+FRESH = 2  # zero the lane's ring and counters before the push
 
 
 @jax.tree_util.register_dataclass
@@ -63,6 +73,24 @@ class PoolState:
     @property
     def n_steps(self) -> int:
         return self.buf.shape[1]
+
+
+def split_lanes(lanes: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Decode the step's `[P]` lane code (int or bool) into its ``(step,
+    fresh)`` bool masks: bit 0 (`STEP`) and bit 1 (`FRESH`)."""
+    code = lanes.astype(jnp.int32)
+    return (code & STEP) != 0, (code & FRESH) != 0
+
+
+def clear_lanes(state: PoolState, fresh: jax.Array) -> PoolState:
+    """Zero the ring and counters of every ``fresh`` lane ([P] bool) and
+    leave the rest as they are — a batch of per-slot resets, inside the
+    step.  A fresh lane then reads as a just-created pool slot."""
+    return PoolState(
+        buf=jnp.where(fresh.reshape(-1, 1, 1), 0, state.buf),
+        cursor=jnp.where(fresh, 0, state.cursor),
+        steps=jnp.where(fresh, 0, state.steps),
+    )
 
 
 def masked_push(state: PoolState, feats: jax.Array, active: jax.Array) -> PoolState:
@@ -119,13 +147,4 @@ def scatter_slot(state: PoolState, slot: int, stream: StreamState) -> PoolState:
         buf=state.buf.at[slot].set(stream.ring.buf.astype(state.buf.dtype)),
         cursor=state.cursor.at[slot].set(stream.ring.cursor.astype(jnp.int32)),
         steps=state.steps.at[slot].set(stream.steps_seen.astype(jnp.int32)),
-    )
-
-
-def clear_slot(state: PoolState, slot: int) -> PoolState:
-    """Zero a slot's ring and counters — per-slot `reset`."""
-    return PoolState(
-        buf=state.buf.at[slot].set(0),
-        cursor=state.cursor.at[slot].set(0),
-        steps=state.steps.at[slot].set(0),
     )

@@ -11,12 +11,19 @@ are kept full by admission/eviction of streams mid-flight:
     out = pool.step({"sensor-a": frame_a, "sensor-b": frame_b})
     state = pool.evict("sensor-a")          # slot free, refill next tick
     pool.admit("sensor-c")                  # NO retrace: shapes unchanged
+    pool.release("sensor-b")                # slot free, state discarded
 
 Key properties (all tested in tests/test_serving.py):
 
   * **One trace.**  The step function traces once per pool; admit / evict /
-    partial ticks are runtime data (the `active` mask and the frame batch),
+    partial ticks are runtime data (the per-lane code and the frame batch),
     never static arguments.
+  * **Slot surgery off the host's eager path.**  A cold `admit` (and
+    `reset`) only marks the slot fresh on the host; the next step zeroes
+    the fresh lanes before its push (`masking.clear_lanes`), the marks
+    riding in the lane code that carries `active`.  `release` frees a slot
+    without reading its state.  Reads in between (`steps_seen`,
+    `window_warm`, `evict`) answer as the zeroed slot would.
   * **Bit-exact per stream.**  Each slot's logits equal an independent
     `StreamSession` fed the same frames, on every backend — batching and
     slot masking are invisible to the numerics.
@@ -53,12 +60,14 @@ import numpy as np
 from repro.core.tcn import StreamState
 from repro.obs.tracer import NULL_TRACER
 from repro.serving.masking import (
+    FRESH,
     PoolState,
-    clear_slot,
+    clear_lanes,
     gather_slot,
     masked_push,
     ordered_windows,
     scatter_slot,
+    split_lanes,
 )
 
 
@@ -136,6 +145,9 @@ class SessionPool:
         self.state = PoolState.create(pool_size, g.tcn_steps, g.feature_channels)
         self._slots: List[Optional[str]] = [None] * pool_size
         self._slot_of: Dict[str, int] = {}
+        # FRESH on the slots admitted cold or reset since the last step:
+        # the step zeroes them first, then the marks are cleared
+        self._fresh = np.zeros((pool_size,), np.int8)
         self._trace_count = 0
         # observability: NULL_TRACER when tracing is off (no-op span, no
         # branch in the hot path); the tracer only ever wraps the jitted
@@ -146,10 +158,11 @@ class SessionPool:
         if self.sharding is not None:
             self.state = self._put(self.state)
 
-        def _step(state: PoolState, frames: jax.Array, active: jax.Array):
+        def _step(state: PoolState, frames: jax.Array, lanes: jax.Array):
             self._trace_count += 1  # python side effect: counts traces only
+            stepping, fresh = split_lanes(lanes)
             feats = deployed.spatial_forward(frames, backend)
-            new = masked_push(state, feats, active)
+            new = masked_push(clear_lanes(state, fresh), feats, stepping)
             logits = deployed.temporal_forward(ordered_windows(new), backend)
             return logits, new
 
@@ -181,8 +194,9 @@ class SessionPool:
         """Claim a free slot for ``stream_id`` and return its index.
 
         With ``state`` given, the stream resumes exactly where it left off
-        (scatter of an evicted/exported `StreamState`); without it the slot
-        is zeroed — a fresh ring, `window_warm` False.  Raises
+        (an eager scatter of an evicted/exported `StreamState`); without it
+        the slot is marked fresh and the next step zeroes it before its push
+        — a fresh ring, `window_warm` False, no device work here.  Raises
         `PoolFullError` when no slot is free and ValueError on a duplicate
         id — admission never silently displaces a live stream.
         """
@@ -196,31 +210,48 @@ class SessionPool:
             ) from None
         with self.tracer.span("pool.admit", track=self.track, slot=slot):
             if state is None:
-                self.state = clear_slot(self.state, slot)
+                self._fresh[slot] = FRESH
             else:
-                self.state = scatter_slot(self.state, slot, state)
-            if self.sharding is not None:
-                self.state = self._put(self.state)
+                self._fresh[slot] = 0
+                self.state = self._put(scatter_slot(self.state, slot, state))
         self._slots[slot] = stream_id
         self._slot_of[stream_id] = slot
         return slot
 
     def evict(self, stream_id: str) -> StreamState:
-        """Release the stream's slot and hand back its `StreamState` pytree
+        """Free the stream's slot and hand back its `StreamState` pytree
         (resume later via ``admit(sid, state=...)`` or
         ``StreamSession.load_state``).  The slot is refillable immediately —
         the next `admit` overwrites it without any retrace."""
-        slot = self._slot_of.pop(self._require(stream_id))
-        self._slots[slot] = None
-        with self.tracer.span("pool.evict", track=self.track, slot=slot):
+        slot = self._vacate(stream_id)
+        with self.tracer.span("pool.evict", track=self.track, slot=slot,
+                              gathered=1):
+            if self._fresh[slot]:  # admitted or reset, not stepped since
+                return StreamState.create(
+                    self.state.n_steps, self.state.buf.shape[2],
+                    dtype=self.state.buf.dtype,
+                )
             return gather_slot(self.state, slot)
 
+    def release(self, stream_id: str) -> None:
+        """Free the stream's slot and discard its state without reading it
+        — `evict` for a departure nobody resumes.  No device work."""
+        slot = self._vacate(stream_id)
+        # the slot leaves the pool here as in `evict`, with nothing to read
+        with self.tracer.span("pool.evict", track=self.track, slot=slot,
+                              gathered=0):
+            pass
+
+    def _vacate(self, stream_id: str) -> int:
+        slot = self._slot_of.pop(self._require(stream_id))
+        self._slots[slot] = None
+        return slot
+
     def reset(self, stream_id: str) -> None:
-        """Per-slot reset: zero this stream's ring and age in place, leaving
-        every other slot untouched (`StreamSession.reset` for one lane)."""
-        self.state = clear_slot(self.state, self._slot_of[self._require(stream_id)])
-        if self.sharding is not None:
-            self.state = self._put(self.state)
+        """Per-slot reset: the next step zeroes this stream's ring and age
+        before its push, leaving every other slot untouched
+        (`StreamSession.reset` for one lane)."""
+        self._fresh[self._slot_of[self._require(stream_id)]] = FRESH
 
     def _require(self, stream_id: str) -> str:
         if stream_id not in self._slot_of:
@@ -272,15 +303,20 @@ class SessionPool:
         """The device half of a tick: run the jitted step on an assembled
         `(batch, active)` pair (see `prepare`) and return the full `[P,
         n_classes]` logits — callers map slots back to stream ids.  The
-        host buffers are copied onto the device at dispatch, so a feeder
-        may refill them as soon as this returns (double buffering)."""
+        slots marked fresh since the last step are ORed into ``active`` as
+        the lane code's `FRESH` bit and zeroed by this step.  The host
+        buffers are copied onto the device at dispatch, so a feeder may
+        refill them as soon as this returns (double buffering)."""
+        lanes = np.asarray(active).astype(np.int8) | self._fresh
         with self.tracer.span("pool.step", track=self.track,
-                              pool_size=self.pool_size):
+                              pool_size=self.pool_size,
+                              fresh=int(np.count_nonzero(lanes & FRESH))):
             logits, self.state = self._step(
                 self.state,
                 self._put(jnp.asarray(batch)),
-                self._put(jnp.asarray(active)),
+                self._put(jnp.asarray(lanes)),
             )
+        self._fresh.fill(0)
         return logits
 
     def step(self, frames: Mapping[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -308,7 +344,8 @@ class SessionPool:
     def steps_seen(self, stream_id: str) -> int:
         """Frames this stream has absorbed since (re)admission — the
         per-slot analogue of `StreamSession.steps_seen`."""
-        return int(self.state.steps[self._slot_of[self._require(stream_id)]])
+        slot = self._slot_of[self._require(stream_id)]
+        return 0 if self._fresh[slot] else int(self.state.steps[slot])
 
     def window_warm(self, stream_id: str) -> bool:
         """True once this stream's full tcn_steps window is real frames."""

@@ -227,9 +227,9 @@ class ContinuousBatcher:
         """Early departure of a stream that has not finished its clip.
 
         A queued request is dropped before ever touching the pool
-        (returns ``"queued"``); an in-flight stream is evicted mid-clip —
-        its slot frees for the next tick's refill, its partial state is
-        discarded, and no `StreamResult` is recorded (returns
+        (returns ``"queued"``); an in-flight stream leaves mid-clip — its
+        slot is released for the next tick's refill, its partial state is
+        discarded unread, and no `StreamResult` is recorded (returns
         ``"inflight"``).  Unknown/already-finished ids raise KeyError.
         """
         for req in self._queue:
@@ -245,7 +245,7 @@ class ContinuousBatcher:
             self.cancelled.append(stream_id)
             return "parked"
         if stream_id in self._inflight:
-            self.pool.evict(stream_id)
+            self.pool.release(stream_id)
             del self._inflight[stream_id], self._next_frame[stream_id]
             del self._admitted_tick[stream_id]
             self.cancelled.append(stream_id)
@@ -455,8 +455,9 @@ class ContinuousBatcher:
         self.feeder.prefetch(self.pool.pool_size, self.pool.frame_shape, items)
 
     def _retire(self, stepping: List[str], out: Dict[str, np.ndarray]) -> int:
-        """Advance each stepped stream's cursor; evict the streams whose
-        clip is done and record their results.  Returns how many departed."""
+        """Advance each stepped stream's cursor; release the slots of the
+        streams whose clip is done (their state is not read) and record
+        their results.  Returns how many departed."""
         departed = 0
         for sid in stepping:
             self._next_frame[sid] += 1
@@ -467,7 +468,7 @@ class ContinuousBatcher:
                 gs.processed += 1
                 gs.last_logits = out[sid]
             if self._next_frame[sid] >= req.frames.shape[0]:
-                self.pool.evict(sid)
+                self.pool.release(sid)
                 self.results.append(
                     StreamResult(
                         stream_id=sid,
@@ -497,7 +498,7 @@ class ContinuousBatcher:
         only), ``admit`` (holding ``pool.admit``), ``assemble``, ``step``
         (holding ``pool.step``), ``demux`` (one host copy of the step's
         logits and its per-stream rows; the copy waits for the step to
-        finish) and ``retire`` (cursors, evictions with their
+        finish) and ``retire`` (cursors, departures with their
         ``pool.evict``, results).  A non-idle tick ends with one ``sched``
         counter sample."""
         tr, track = self.tracer, self.track

@@ -26,12 +26,13 @@ from repro.serving import (
     PoolState,
     SessionPool,
     StreamRequest,
-    clear_slot,
+    clear_lanes,
     gather_slot,
     masked_push,
     ordered_windows,
     scatter_slot,
 )
+from repro.serving.masking import FRESH, STEP, split_lanes
 
 BACKENDS = ("ref", "fused")
 
@@ -117,10 +118,21 @@ class TestMasking:
     def test_clear_slot_is_per_slot(self):
         state = PoolState.create(2, 4, 2)
         state = masked_push(state, jnp.ones((2, 2)), jnp.array([True, True]))
-        state = clear_slot(state, 0)
+        state = clear_lanes(state, jnp.array([True, False]))
         assert not np.asarray(state.buf[0]).any()
         assert np.asarray(state.buf[1, 0]).all()
         assert list(np.asarray(state.steps)) == [0, 1]
+        assert list(np.asarray(state.cursor)) == [0, 1]
+
+    def test_split_lanes_reads_bool_masks_and_codes(self):
+        """A bool mask is the lane code with the FRESH bit unset."""
+        step, fresh = split_lanes(jnp.array([True, False, True]))
+        assert list(np.asarray(step)) == [True, False, True]
+        assert not np.asarray(fresh).any()
+        code = jnp.array([0, STEP, FRESH, STEP | FRESH], jnp.int8)
+        step, fresh = split_lanes(code)
+        assert list(np.asarray(step)) == [False, True, False, True]
+        assert list(np.asarray(fresh)) == [False, False, True, True]
 
 
 # ---------------------------------------------------------------------------
@@ -263,6 +275,206 @@ class TestSessionPool:
         dep = prog.quantize(prog.init(jax.random.PRNGKey(0)))
         with pytest.raises(ValueError, match="no TCN memory"):
             dep.serve(2)
+
+
+# ---------------------------------------------------------------------------
+# slot surgery as data: fresh lanes zeroed by the step, release without read
+# ---------------------------------------------------------------------------
+
+def zero_state_of(pool):
+    buf = np.asarray(pool.state.buf)
+    return np.zeros(buf.shape[1:], buf.dtype)
+
+
+class TestFreshLanes:
+    """A cold admit or a reset marks the slot fresh on the host and the
+    next step zeroes it; `release` frees a slot without reading it.  Every
+    pooled logit still equals a lone session, and reads made while a slot
+    is pending answer as the zeroed slot would."""
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_release_then_refill_same_slot_under_churn(self, deployed, backend):
+        """Streams of different lengths share 2 slots; each departure is a
+        `release` and its slot is refilled before the next step, so every
+        refill lands on a slot whose previous tenant was never read."""
+        lengths = [3, 1, 4, 2, 5, 1, 3]
+        frames = clips_for(deployed.graph, len(lengths), max(lengths), seed=40)
+        pool = SessionPool(deployed, 2, backend=backend)
+        queue = list(range(len(lengths)))
+        fed, oracles = {}, {}
+        while queue or fed:
+            while queue and pool.free_slots:
+                i = queue.pop(0)
+                pool.admit(f"s{i}")
+                assert pool.steps_seen(f"s{i}") == 0
+                fed[i], oracles[i] = 0, deployed.stream(batch=1, backend=backend)
+            out = pool.step({f"s{i}": frames[i, t] for i, t in fed.items()})
+            for i, t in list(fed.items()):
+                want = oracles[i].step(frames[i:i + 1, t])
+                exact(out[f"s{i}"], np.asarray(want)[0])
+                fed[i] += 1
+                assert pool.steps_seen(f"s{i}") == fed[i]
+                if fed[i] == lengths[i]:
+                    pool.release(f"s{i}")
+                    del fed[i]
+        assert len(pool) == 0 and pool.trace_count == 1
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_admit_then_evict_before_any_step_is_zero_state(
+        self, deployed, backend
+    ):
+        """A slot whose previous tenant stepped 5 frames: a newcomer admitted
+        there reads 0 / not warm, and evicted before any step hands back a
+        zero `StreamState`, never the old tenant's ring."""
+        T = deployed.graph.tcn_steps
+        frames = clips_for(deployed.graph, 2, T + 2, seed=41)
+        pool = SessionPool(deployed, 1, backend=backend)
+        pool.admit("old")
+        for t in range(T + 1):
+            pool.step({"old": frames[0, t]})
+        assert pool.window_warm("old")
+        pool.release("old")
+        pool.admit("new")
+        assert pool.steps_seen("new") == 0 and not pool.window_warm("new")
+        st = pool.evict("new")
+        exact(st.ring.buf, zero_state_of(pool))
+        assert int(st.ring.cursor) == 0 and int(st.steps_seen) == 0
+        assert st.ring.buf.dtype == pool.state.buf.dtype
+        # the zero state resumes like a fresh session
+        pool.admit("new", state=st)
+        fresh = deployed.stream(batch=1, backend=backend)
+        for t in range(T + 1):
+            out = pool.step({"new": frames[1, t]})
+            exact(out["new"], np.asarray(fresh.step(frames[1:2, t]))[0])
+            assert pool.window_warm("new") == (t + 1 >= T)
+        assert pool.trace_count == 1
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_released_slot_refilled_with_state_keeps_migrated_ring(
+        self, deployed, backend
+    ):
+        """A slot left marked fresh by a cold admit that never stepped, then
+        refilled with `admit(state=...)`: the step must not zero the
+        migrated ring."""
+        frames = clips_for(deployed.graph, 1, 7, seed=42)[0]
+        oracle = deployed.stream(batch=1, backend=backend)
+        src = SessionPool(deployed, 2, backend=backend)
+        src.admit("m")
+        outs = [src.step({"m": frames[t]})["m"] for t in range(3)]
+        state = src.evict("m")
+        dst = SessionPool(deployed, 1, backend=backend)
+        dst.admit("ghost")                  # marks the only slot fresh
+        dst.release("ghost")                # leaves before any step
+        dst.admit("m", state=state)
+        assert dst.steps_seen("m") == 3
+        outs += [dst.step({"m": frames[t]})["m"] for t in range(3, 7)]
+        for t in range(7):
+            exact(outs[t], np.asarray(oracle.step(frames[None, t]))[0])
+        assert src.trace_count == 1 and dst.trace_count == 1
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_reset_through_the_fresh_lane(self, deployed, backend):
+        """reset() zeroes one lane through the next step, even a step in
+        which the reset stream does not push (FRESH without STEP); the
+        neighbour is untouched."""
+        frames = clips_for(deployed.graph, 2, 7, seed=43)
+        pool = SessionPool(deployed, 2, backend=backend)
+        keep = deployed.stream(batch=1, backend=backend)
+        pool.admit("a"); pool.admit("b")
+        for t in range(3):
+            pool.step({"a": frames[0, t], "b": frames[1, t]})
+            keep.step(frames[0:1, t])
+        pool.reset("b")
+        assert pool.steps_seen("b") == 0 and pool.steps_seen("a") == 3
+        out = pool.step({"a": frames[0, 3]})  # b is zeroed, not stepped
+        exact(out["a"], np.asarray(keep.step(frames[0:1, 3]))[0])
+        on_device = gather_slot(pool.state, pool.slot_of("b"))
+        exact(on_device.ring.buf, zero_state_of(pool))
+        assert int(on_device.ring.cursor) == 0 == int(on_device.steps_seen)
+        assert pool.steps_seen("a") == 4
+        fresh = deployed.stream(batch=1, backend=backend)
+        for t in range(4, 7):
+            out = pool.step({"a": frames[0, t], "b": frames[1, t]})
+            exact(out["a"], np.asarray(keep.step(frames[0:1, t]))[0])
+            exact(out["b"], np.asarray(fresh.step(frames[1:2, t]))[0])
+        assert pool.trace_count == 1
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_step_prepared_with_plain_bool_active_equals_step(
+        self, deployed, backend
+    ):
+        """A caller's bool `active` still drives the step: the pool ORs its
+        pending fresh marks into it, so a slot refilled after a departure
+        is zeroed exactly as through `step`."""
+        frames = clips_for(deployed.graph, 3, 4, seed=44)
+        a = SessionPool(deployed, 2, backend=backend)
+        b = SessionPool(deployed, 2, backend=backend)
+        for p in (a, b):
+            p.admit("x"); p.admit("y")
+        for t in range(4):
+            if t == 2:
+                for p in (a, b):
+                    p.release("y"); p.admit("z")
+            fr = {sid: frames[i, t] for i, sid in enumerate(("x", "y", "z"))
+                  if sid in a}
+            active = np.zeros((2,), bool)
+            batch = np.zeros((2, *a.frame_shape), np.float32)
+            for sid, f in fr.items():
+                active[a.slot_of(sid)] = True
+                batch[a.slot_of(sid)] = np.asarray(f)
+            logits = a.step_prepared(batch, active)
+            assert active.dtype == bool          # the caller's mask untouched
+            want = b.step(fr)
+            for sid in fr:
+                exact(logits[a.slot_of(sid)], want[sid])
+        assert a.trace_count == 1 and b.trace_count == 1
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_retire_and_cancel_never_gather(self, deployed, backend, monkeypatch):
+        """Departures and in-flight cancellations discard the state, so the
+        batcher never reads a slot back: `gather_slot` may not run."""
+        import repro.serving.pool as pool_mod
+
+        def refuse(*_):
+            raise AssertionError("gather_slot called for a discarded state")
+
+        monkeypatch.setattr(pool_mod, "gather_slot", refuse)
+        lengths = [2, 4, 1, 3, 3]
+        frames = clips_for(deployed.graph, len(lengths), 4, seed=45)
+        pool = SessionPool(deployed, 2, backend=backend)
+        batcher = ContinuousBatcher(pool)
+        for i, n in enumerate(lengths):
+            batcher.submit(StreamRequest(f"s{i}", frames[i, :n]))
+        batcher.tick()
+        assert batcher.cancel("s1") == "inflight"
+        results = batcher.run()
+        assert {r.stream_id for r in results} == {"s0", "s2", "s3", "s4"}
+        for r in results:
+            i = int(r.stream_id[1:])
+            oracle = deployed.stream(batch=1, backend=backend)
+            for t in range(lengths[i]):
+                want = oracle.step(frames[i:i + 1, t])
+            exact(r.logits, np.asarray(want)[0])
+        assert pool.trace_count == 1
+
+    def test_spans_count_fresh_lanes_and_gathers(self, deployed):
+        """`pool.step` carries how many lanes it zeroed; `pool.evict` says
+        whether the slot's state was read (evict) or not (release)."""
+        from repro.obs import Tracer
+
+        frames = clips_for(deployed.graph, 3, 2, seed=46)
+        tracer = Tracer()
+        pool = SessionPool(deployed, 3, backend="ref", tracer=tracer)
+        pool.admit("a"); pool.admit("b")
+        pool.step({"a": frames[0, 0]})        # b zeroed too, not stepped
+        pool.admit("c")
+        pool.step({"a": frames[0, 1], "c": frames[2, 0]})
+        pool.step({"c": frames[2, 1]})
+        pool.evict("a"); pool.release("b")
+        spans = [e for e in tracer.events() if e.phase == "X"]
+        assert [e.args["fresh"] for e in spans if e.name == "pool.step"] == [2, 1, 0]
+        assert [e.args["gathered"] for e in spans if e.name == "pool.evict"] == [1, 0]
+        assert pool.trace_count == 1
 
 
 # ---------------------------------------------------------------------------
@@ -603,6 +815,25 @@ for t in range(3):
     a, b = sharded.step(fr), plain.step(fr)
     for k in fr:
         np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+# admit/release churn: each chip zeroes its own fresh lanes in the step
+live = {f"s{i}": i for i in range(8)}
+nxt = 8
+for t in range(6):
+    for sid in [s for s, i in live.items() if (i + t) % 3 == 0]:
+        for p in (sharded, plain):
+            p.release(sid)
+        del live[sid]
+        for p in (sharded, plain):
+            p.admit(f"s{nxt}")
+        live[f"s{nxt}"] = nxt
+        nxt += 1
+    fr = {s: frames[i % 8, t % 3] for s, i in live.items() if (i + t) % 4}
+    a, b = sharded.step(fr), plain.step(fr)
+    for k in fr:
+        np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+    for k in live:
+        assert sharded.steps_seen(k) == plain.steps_seen(k)
+assert len(sharded.state.buf.sharding.device_set) == 4
 assert sharded.trace_count == 1
 print("SHARD-RULES-OK")
 """

@@ -168,11 +168,9 @@ def test_matmul_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-def test_sharded_pool_step_compiles_for_four_chips(topo, monkeypatch):
-    """The dvs_cnn_tcn pool step with its pool axis over 4 chips: a Pallas
-    call cannot be partitioned by the compiler, so the pool must map its
-    step over the devices (`shard_map`) — one kernel per layer, no
-    collective anywhere."""
+def _sharded_pool_step_hlo(topo, monkeypatch, lane_dtype):
+    """The dvs_cnn_tcn pool step at 8 slots over the 4 chips of ``topo``,
+    compiled with a ``lane_dtype`` lane argument; returns the HLO text."""
     import repro.kernels.ops as ops
     from repro import api
     from repro.serving import SessionPool
@@ -191,8 +189,26 @@ def test_sharded_pool_step_compiles_for_four_chips(topo, monkeypatch):
 
     state = jax.tree_util.tree_map(spec, pool.state)
     frames = _spec((8, *pool.frame_shape), jnp.float32, sharding)
-    active = _spec((8,), jnp.bool_, sharding)
-    hlo = pool._step.lower(state, frames, active).compile().as_text()
+    lanes = _spec((8,), lane_dtype, sharding)
+    return pool._step.lower(state, frames, lanes).compile().as_text()
+
+
+def test_sharded_pool_step_compiles_for_four_chips(topo, monkeypatch):
+    """The dvs_cnn_tcn pool step with its pool axis over 4 chips: a Pallas
+    call cannot be partitioned by the compiler, so the pool must map its
+    step over the devices (`shard_map`) — one kernel per layer, no
+    collective anywhere."""
+    hlo = _sharded_pool_step_hlo(topo, monkeypatch, jnp.bool_)
+    assert "tpu_custom_call" in hlo
+    for collective in ("all-gather", "all-reduce", "all-to-all"):
+        assert collective not in hlo
+
+
+def test_sharded_pool_step_with_lane_code_has_no_collective(topo, monkeypatch):
+    """The same step as the pool runs it: an int8 lane code whose FRESH bit
+    zeroes lanes before the push.  Each chip zeroes its own lanes — still
+    no collective."""
+    hlo = _sharded_pool_step_hlo(topo, monkeypatch, jnp.int8)
     assert "tpu_custom_call" in hlo
     for collective in ("all-gather", "all-reduce", "all-to-all"):
         assert collective not in hlo
