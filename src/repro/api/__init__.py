@@ -6,13 +6,14 @@ One network definition drives every execution mode:
     prog     = get_net("cifar10_tnn")
     params   = prog.init(jax.random.PRNGKey(0))
     deployed = prog.quantize(params)
-    logits   = deployed.forward(x, backend="fused")  # | "pallas" | "ref" | "interpret"
+    logits   = deployed.forward(x)                   # backend="fused" | "ref" | "bitsim"
     report   = deployed.silicon_report(v=0.5)            # paper Table 1 loop
 
 Submodules:
     graph     LayerSpec / CutieGraph + constructor helpers
     quantize  THE quantize->pad->pack path (shared with kernels/ops.py)
     program   CutieProgram / DeployedProgram / StreamSession / SiliconReport
+              (the deploy walk itself is `repro.sim.PlanExecutor`)
     registry  register_net / get_net, seeded with the paper's networks
 
 Training these programs is `repro.train` (STE QAT + schedules + the

@@ -7,48 +7,45 @@ the paper's silicon implements:
     params   = prog.init(jax.random.PRNGKey(0))
     logits   = prog.forward_qat(params, x)      # STE fake-quant training path
     deployed = prog.quantize(params, calib=x)   # packed 2-bit weights
-    logits   = deployed.forward(x, backend="fused")    # | "pallas" | "ref" | "interpret"
+    logits   = deployed.forward(x)              # backend="fused" | "ref" | "bitsim"
     session  = deployed.stream(batch=4)         # TCN ring memory (temporal)
     pool     = deployed.serve(pool_size=8)      # multi-sensor continuous batching
     report   = deployed.silicon_report(v=0.5)   # cycles/energy vs Table 1
 
-Execution semantics per layer kind are identical across paths; the QAT path
-uses STE fake-quant + per-channel batch-norm scaling, the deploy path runs
-the packed 2-bit weights through the Pallas kernels with the BN statistics
-folded into the per-OCU scale (``calib``) or a fan-in normalization fallback.
-With ``calib`` given AND the graph's ``qat_per_channel=True`` (so both paths
-share one quantization grid), forward_qat and deployed.forward agree to
-float round-off on the calibration distribution; on the default per-layer
-QAT grid the grids differ slightly and agreement is approximate — both
-tested in tests/test_api.py.
+The QAT path walks the graph with STE fake-quant + per-channel batch-norm
+scaling.  The deploy path lowers the graph once to a `sim.ExecutionPlan`,
+binds the packed 2-bit weights as `sim.WeightMemory` images with the BN
+statistics folded into the per-OCU scale (``calib``) or a fan-in
+normalization fallback, and walks the plan with `sim.PlanExecutor` — the
+one deploy interpreter, shared with programs loaded from a ``.cutie``
+artifact (`PlanProgram`).  With ``calib`` given AND the graph's
+``qat_per_channel=True`` (so both paths share one quantization grid),
+forward_qat and deployed.forward agree to float round-off on the
+calibration distribution; on the default per-layer QAT grid the grids
+differ slightly and agreement is approximate — both tested in
+tests/test_api.py.
 
-Backends:
-    fused      Pallas kernels with conv+scale+ternarize(+2x2 max-pool) fused
-               into one launch per layer, int8 ternary activations between
-               layers — the silicon's 2-bit inter-layer memory model, and
-               the deploy default for serving
-    pallas     Pallas TPU kernels (auto-interpret on CPU), float activations
-               re-ternarized between layers
-    interpret  Pallas kernels, interpreter forced — debugging on any host
-    ref        pure-jnp oracles from kernels/ref.py — the semantics anchor
-    bitsim     `repro.sim` plan executor: lowers the graph to an explicit
-               `ExecutionPlan` (OCU/C_in tiles, trit-packed weight-memory
-               images) and runs it tile-by-tile — the cycle-counted
-               microarchitecture simulator's functional half, bit-exact
-               vs ref/fused on ternary data
+Backends (the executor's per-layer choice):
+    fused   conv+scale+shortcut+ternarize(+max-pool) in one packed kernel
+            launch per layer (native select-decode on CPU, Pallas on TPU),
+            int8 ternary activations between layers — the silicon's 2-bit
+            inter-layer memory model and the default of every entry point
+    ref     pure-jnp oracles from kernels/ref.py, then ternarize and pool
+    bitsim  the plan's OCU/C_in tile passes over the trit-packed images —
+            the cycle-counted microarchitecture simulator's functional half
 
-All five produce identical logits — bit-exact for "fused"/"bitsim" vs "ref"
-whenever every inter-layer tensor is ternary or a dyadic rational of ternary
-values
-(true for all registry nets: their global_pool windows are power-of-two
-sized), since these paths then accumulate exactly in float32 regardless of
-summation order.  Tested in tests/test_fused_backend.py and gated in CI by
-benchmarks/backend_bench.py; a net whose global_pool mean divides by a
-non-power-of-two could differ in the last ulp at a threshold crossing.
+All three produce bit-identical logits whenever every inter-layer tensor is
+ternary or a dyadic rational of ternary values (true for all registry nets:
+their global_pool windows are power-of-two sized), since they then
+accumulate exactly in float32 regardless of summation order.  Tested in
+tests/test_api.py and tests/test_fused_backend.py; a net whose global_pool
+mean divides by a non-power-of-two could differ in the last ulp at a
+threshold crossing.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -70,7 +67,7 @@ from repro.core.ternary import clamp_threshold, ste_ternary_acts, ste_ternary_we
 from repro.kernels.ops import ternary_conv2d
 from repro.kernels.ref import ternary_conv2d_ref
 
-BACKENDS = ("fused", "pallas", "ref", "interpret", "bitsim")
+BACKENDS = ("fused", "ref", "bitsim")
 SILICON_SOURCES = ("analytic", "sim")
 _BN_EPS = 1e-6
 
@@ -101,52 +98,39 @@ def _bn_sd(y: jax.Array) -> jax.Array:
     return jnp.std(y.astype(jnp.float32), axis=tuple(range(y.ndim - 1)))
 
 
-def effective_scale(entry: Dict, fan_in: int) -> jax.Array:
+def effective_scale(entry: Dict, fan_in: int) -> np.ndarray:
     """THE per-OCU effective-scale fold: calibration BN std folded into the
-    TWN alpha, or a 1/sqrt(fan-in) normalization without calibration.  Every
-    consumer — the deploy interpreter below AND the simulator's
-    `repro.sim.memory.WeightMemory` — must fold through this one function:
-    the bitsim-vs-ref bit-exactness contract rides on the constants being
-    the same float32 values."""
+    TWN alpha, or a 1/sqrt(fan-in) normalization without calibration.
+    `repro.sim.memory.WeightMemory` folds each layer through it once, on
+    the host in IEEE float32, and every backend reads the constant."""
+    scale = np.asarray(entry["scale"], np.float32)
     if "bn_sd" in entry:
-        return entry["scale"] / (entry["bn_sd"] + _BN_EPS)
-    return entry["scale"] / jnp.sqrt(float(fan_in))
-
-
-def _ternarize(y: jax.Array, threshold: float) -> jax.Array:
-    return jnp.where(jnp.abs(y) > threshold, jnp.sign(y), 0.0)
+        return scale / (np.asarray(entry["bn_sd"], np.float32) + np.float32(_BN_EPS))
+    return scale / np.sqrt(np.float32(fan_in))
 
 
 def _dispatch_conv(x, packed, eff_scale, backend: str, *,
                    threshold=0.5, pool: int = 0,
                    block_cout: Optional[int] = None, residual=None):
-    """One SAME ternary conv through the selected backend.  ``x`` must
-    already be channel-padded to 4 * packed.shape[2].  ``threshold`` is a
-    scalar or per-channel [C_out] vector (the ThFU comparator constants).
-    ``block_cout`` is the layer's plan-driven kernel block
-    (`kernels.autotune`; None = the plan-less 128 default).  ``residual``
-    is the layer's shortcut map at its output size (`shortcut_map`), added
-    to the scaled accumulator before the threshold.
+    """One SAME ternary conv, as `sim.PlanExecutor` runs it on ``backend``
+    "fused" or "ref".  ``x`` must already be channel-padded to
+    4 * packed.shape[2].  ``residual`` is the layer's shortcut map at its
+    output size (`shortcut_map`), added to the scaled accumulator before
+    the threshold.
 
-    The "fused" backend runs the whole CUTIE layer — conv, per-OCU scale,
-    shortcut add, threshold unit, optional ``pool``-window max-pool — in a
-    single packed launch (native select-decode datapath on CPU, the Pallas
-    kernel on TPU) and emits int8 ternary activations; "pallas"/"interpret"
-    pin the Pallas machinery (compiled/interpreted), return the scaled
-    float accumulator with the shortcut added, and leave ternarize/pool to
-    the caller."""
-    check_backend(backend)
+    "fused" runs the whole CUTIE layer — conv, per-OCU scale, shortcut
+    add, threshold unit (``threshold``: scalar or per-channel [C_out]),
+    optional ``pool``-window max-pool — in a single packed launch (native
+    select-decode datapath on CPU, the Pallas kernel on TPU, with the
+    layer's plan-driven ``block_cout``) and emits int8 ternary activations.
+    "ref" is the `kernels/ref.py` oracle: the scaled float accumulator,
+    threshold and pool left to the caller."""
     if backend == "ref":
         return ternary_conv2d_ref(x, packed, eff_scale, residual=residual)
-    if backend == "fused":
-        return ternary_conv2d(
-            x, packed, eff_scale, fuse_ternary=True, threshold=threshold,
-            fuse_pool=pool, out_dtype=jnp.int8, block_cout=block_cout,
-            residual=residual,
-        )
     return ternary_conv2d(
-        x, packed, eff_scale, block_cout=block_cout, residual=residual,
-        impl="interpret" if backend == "interpret" else "pallas",
+        x, packed, eff_scale, fuse_ternary=True, threshold=threshold,
+        fuse_pool=pool, out_dtype=jnp.int8, block_cout=block_cout,
+        residual=residual,
     )
 
 
@@ -445,181 +429,50 @@ class CutieProgram:
         return silicon_report(self.graph, v=v, hw=hw, source=source)
 
 
-@dataclasses.dataclass
-class DeployedProgram:
-    """Packed 2-bit weights + the deploy interpreter over them.
+class PlanProgram:
+    """The execution surface every deploy program shares: each backend's
+    `repro.sim.PlanExecutor` walks ``self.plan`` over ``self.memory``.
 
-    ``tables`` layout (shared with the legacy ``quantize_for_deploy``):
-      conv: [{"packed", "scale", ("bn_sd")} ...]   packed along C_in
-      tcn:  [{"packed", "scale", "dilation", ("bn_sd")} ...]  §4-projected 2-D
-      fc:   {"t", "scale"}                          dense int8 trits
-    """
+    A subclass supplies ``graph`` (a `CutieGraph`, or the artifact's
+    graph-free `artifact.ProgramInfo` — serving reads only its metadata),
+    ``plan`` (the `sim.ExecutionPlan`) and ``memory`` (the
+    `sim.WeightMemory` images)."""
 
-    graph: CutieGraph
-    tables: Dict
+    @functools.cached_property
+    def _executors(self) -> Dict:
+        return {}
 
-    # -- per-layer-kind execution -----------------------------------------
-
-    def _eff_scale(self, entry: Dict, fan_in: int) -> jax.Array:
-        return effective_scale(entry, fan_in)
-
-    def _bitsim(self):
-        """The lazily-built `repro.sim.PlanExecutor` behind backend="bitsim":
-        graph lowered to an `ExecutionPlan`, packed tables bound as
-        weight-memory images.  Cached — lowering is pure and the tables are
-        immutable once quantized."""
-        ex = getattr(self, "_bitsim_exec", None)
+    def _executor(self, backend: str):
+        ex = self._executors.get(backend)
         if ex is None:
-            from repro.sim import PlanExecutor
+            from repro.sim.execute import PlanExecutor
 
-            ex = self._bitsim_exec = PlanExecutor.for_deployed(self)
+            ex = self._executors[backend] = PlanExecutor(
+                self.plan, self.memory, backend
+            )
         return ex
 
-    def execution_plan(self):
-        """This program's compiled `ExecutionPlan` (see `repro.sim.plan`)."""
-        return self._bitsim().plan
-
     @property
-    def kernel_blocks(self):
-        """Plan-driven autotuned kernel blocks, ``{"conv": [KernelBlock],
-        "tcn": [...]}`` in table order (`kernels.autotune.kernel_block_plan`
-        over this graph's lowered `ExecutionPlan`): the same `TileAssign`
-        geometry that prices cycles picks each layer's block_cout.  Cached —
-        lowering is pure; computed straight from `sim.plan.lower` so the
-        deploy hot path never has to materialize weight-memory images."""
-        kb = getattr(self, "_kernel_blocks", None)
-        if kb is None:
-            from repro.kernels.autotune import kernel_block_plan
-            from repro.sim.plan import lower
+    def nbytes(self) -> int:
+        """Total packed weight-image bytes (the device's weight SCM load)."""
+        return self.memory.nbytes
 
-            kb = self._kernel_blocks = kernel_block_plan(lower(self.graph))
-        return kb
-
-    def _fc(self, x: jax.Array) -> jax.Array:
-        fc = self.tables["fc"]
-        if not jnp.issubdtype(x.dtype, jnp.floating):
-            x = x.astype(jnp.float32)  # fused backend hands int8 trits over
-        # Dot the raw trits FIRST, scale per class AFTER — the OPU's order
-        # (integer accumulate -> fold scale).  With ternary/dyadic inputs
-        # the x @ t reduction is integer-valued and therefore exact in
-        # float32 under ANY summation order, so the logits are identical
-        # across batch sizes and eager/jit — the serving-pool contract that
-        # slot p of a P-wide batch reproduces a lone batch-1 session
-        # bit-for-bit.  (Folding the scale into the weights before the dot
-        # breaks this: the batched gemm reassociates per shape and drifts
-        # in the last ulp.)
-        return (x @ fc["t"].astype(x.dtype)) * fc["scale"]
-
-    def spatial_forward(self, x: jax.Array, backend: str = "pallas") -> jax.Array:
+    def spatial_forward(self, x: jax.Array, backend: str = "fused") -> jax.Array:
         """Frontend (or whole spatial net) on packed weights: [B,H,W,C] ->
-        feature vector / logits.  On the "fused" backend each conv layer is
-        one kernel launch (conv+scale+ternarize, plus the following pool
-        layer sunk into the epilogue) emitting int8 ternary activations —
-        the pool LayerSpec it absorbed is then skipped here.  A shortcut
-        source's output stays live until its consumer, whose launch takes
-        it as the residual operand."""
-        if backend == "bitsim":
-            return self._bitsim().spatial_forward(x)
-        g = self.graph
-        ci = 0
-        fused_pools = 0
-        blocks = None if backend == "ref" else self.kernel_blocks["conv"]
-        sources, saved = g.shortcut_sources, {}  # saved: maps kept live
-        for i, l in enumerate(g.spatial_layers):
-            if l.kind == "conv2d":
-                entry = self.tables["conv"][ci]
-                bc = None if blocks is None else blocks[ci].block_cout
-                ci += 1
-                c_pad = 4 * entry["packed"].shape[2]
-                res = None
-                if l.shortcut is not None:
-                    res = shortcut_map(saved.pop(l.shortcut),
-                                       (*x.shape[:3], l.c_out))
-                x = _pad_channels(x, c_pad)
-                eff = self._eff_scale(entry, l.kernel[0] * l.kernel[1] * c_pad)
-                if backend == "fused":
-                    pool = entry.get("pool", 0)
-                    x = _dispatch_conv(
-                        x, entry["packed"], eff, backend,
-                        threshold=entry.get("threshold", g.act_threshold), pool=pool,
-                        block_cout=bc, residual=res,
-                    )
-                    fused_pools += 1 if pool else 0
-                else:
-                    y = _dispatch_conv(x, entry["packed"], eff, backend,
-                                       block_cout=bc, residual=res)
-                    x = _ternarize(y, entry.get("threshold", g.act_threshold))
-                if l.stride > 1:
-                    # post-ternarize subsample == strided conv (elementwise
-                    # epilogue); a strided conv never absorbs a pool, so the
-                    # fused int8 output subsamples the same way
-                    x = x[:, :: l.stride, :: l.stride, :]
-                if i in sources:
-                    saved[i] = x
-            elif l.kind == "pool":
-                if fused_pools:
-                    fused_pools -= 1
-                else:
-                    x = _pool(x, l.window)
-            elif l.kind == "global_pool":
-                x = x.mean(axis=(1, 2))
-            elif l.kind == "flatten":
-                x = x.reshape(x.shape[0], -1)
-            elif l.kind == "fc":
-                x = self._fc(x)
-        return x
+        feature vector / logits."""
+        return self._executor(backend).spatial_forward(x)
 
-    def temporal_forward(self, feats: jax.Array, backend: str = "pallas") -> jax.Array:
+    def temporal_forward(self, feats: jax.Array, backend: str = "fused") -> jax.Array:
         """TCN head over the ordered window [B, T, C] -> logits, via the §4
         mapping + the 2-D conv kernel (SAME pad adjusted to causal)."""
-        if backend == "bitsim":
-            return self._bitsim().temporal_forward(feats)
-        g = self.graph
-        x = feats
-        blocks = None if backend == "ref" else self.kernel_blocks["tcn"]
-        for ti, (entry, l) in enumerate(
-            zip(self.tables["tcn"], (l for l in g.temporal_layers if l.kind == "tcn"))
-        ):
-            z = wrap_time_axis(x, entry["dilation"])
-            # the kernel runs SAME (top pad (kh-1)//2); add the rest of the
-            # causal (kh-1) pad so it matches conv2d_undilated's schedule
-            kh = l.kernel[0]
-            zp = jnp.pad(z, ((0, 0), ((kh - 1) - (kh - 1) // 2, 0), (0, 0), (0, 0)))
-            # pack granularity: weights are padded to C_in % 4 == 0 at
-            # quantize time; pad the activations to match (zero trits are
-            # free), as spatial_forward does — widths like c=9 need this.
-            # fan-in stays the UNPADDED width: the sim's WeightMemory folds
-            # taps * c_in, and the bit-exactness contract rides on both
-            # paths folding the same float32 constants.
-            eff = self._eff_scale(entry, l.taps * zp.shape[-1])
-            zp = _pad_channels(zp, 4 * entry["packed"].shape[2])
-            bc = None if blocks is None else blocks[ti].block_cout
-            if backend == "fused":
-                y2 = _dispatch_conv(
-                    zp, entry["packed"], eff, backend,
-                    threshold=entry.get("threshold", g.act_threshold),
-                    block_cout=bc,
-                )[:, : z.shape[1]]
-                x = unwrap_time_axis(y2, x.shape[1])
-            else:
-                y2 = _dispatch_conv(zp, entry["packed"], eff, backend,
-                                    block_cout=bc)[:, : z.shape[1]]
-                y = unwrap_time_axis(y2, x.shape[1])
-                x = _ternarize(y, entry.get("threshold", g.act_threshold))
-        for l in g.temporal_layers:
-            if l.kind == "last_step":
-                x = x[:, -1, :]
-            elif l.kind == "fc":
-                x = self._fc(x)
-        return x
+        return self._executor(backend).temporal_forward(feats)
 
-    def forward(self, x: jax.Array, backend: str = "pallas") -> jax.Array:
+    def forward(self, x: jax.Array, backend: str = "fused") -> jax.Array:
         """Whole-network deploy inference.  Spatial graphs: [B,H,W,C] ->
         logits.  Temporal graphs: frames [B,T,H,W,C] -> logits over the
         ring window (last tcn_steps frames, zero history on the left) —
         bit-identical to streaming the frames through ``stream()`` (tested,
         including clips longer than the ring)."""
-        check_backend(backend)
         g = self.graph
         if not g.is_temporal:
             return self.spatial_forward(x, backend)
@@ -631,12 +484,11 @@ class DeployedProgram:
     # -- streaming (the silicon's autonomous mode) ------------------------
 
     def stream_step(
-        self, stream: TCNStream, frame: jax.Array, backend: str = "pallas"
+        self, stream: TCNStream, frame: jax.Array, backend: str = "fused"
     ) -> Tuple[jax.Array, TCNStream]:
         """Pure-functional step: one sensor frame -> (logits, new stream).
         CNN frontend -> push feature vector into the ring -> TCN head over
         the ordered window; past frames are never recomputed."""
-        check_backend(backend)
         feat = self.spatial_forward(frame, backend)
         stream = stream.push(feat.astype(stream.buf.dtype))
         window = stream.ordered()
@@ -645,12 +497,12 @@ class DeployedProgram:
         return self.temporal_forward(window, backend), stream
 
     def stream(
-        self, batch: Optional[int] = None, backend: str = "pallas", jit: bool = True
+        self, batch: Optional[int] = None, backend: str = "fused", jit: bool = True
     ) -> "StreamSession":
         """Open a stateful streaming session over this program's TCN ring
         (temporal graphs only): ``session.step(frame)`` per sensor frame.
 
-            session = deployed.stream(batch=4, backend="fused")
+            session = deployed.stream(batch=4)
             for frame in frames:
                 logits = session.step(frame)     # one label per frame
         """
@@ -681,6 +533,37 @@ class DeployedProgram:
         router.register(name or self.graph.name, self)
         return router
 
+
+@dataclasses.dataclass
+class DeployedProgram(PlanProgram):
+    """Packed 2-bit weights of a `CutieGraph`, executed through its plan.
+
+    ``tables`` layout (shared with the legacy ``quantize_for_deploy``):
+      conv: [{"packed", "scale", ("bn_sd")} ...]   packed along C_in
+      tcn:  [{"packed", "scale", "dilation", ("bn_sd")} ...]  §4-projected 2-D
+      fc:   {"t", "scale"}                          dense int8 trits
+
+    ``plan`` and ``memory`` are derived once, on first use (at the latest
+    the first trace of a forward): the graph lowered by `sim.lower`, the
+    tables bound as `sim.WeightMemory` images with each layer's effective
+    scale folded (`effective_scale`)."""
+
+    graph: CutieGraph
+    tables: Dict
+
+    @functools.cached_property
+    def plan(self):
+        from repro.sim.plan import lower
+
+        return lower(self.graph)
+
+    @functools.cached_property
+    def memory(self):
+        from repro.sim.memory import WeightMemory
+
+        return WeightMemory.from_tables(self.plan, self.tables,
+                                        self.graph.act_threshold)
+
     # -- artifact export (repro.artifact) ----------------------------------
 
     def to_artifact_bytes(self) -> bytes:
@@ -706,10 +589,10 @@ class DeployedProgram:
     ) -> "SiliconReport":
         """Cycles/energy for the deployed graph at supply ``v`` — see
         module-level `silicon_report` (the Table-1 loop).  ``source="sim"``
-        prices the same `ExecutionPlan` the bitsim backend executes, with
+        prices the same `ExecutionPlan` every backend executes, with
         dynamic energy priced on THIS program's packed weight images
         (sparsity-aware) rather than the ideal dense schedule."""
-        memory = self._bitsim().memory if source == "sim" else None
+        memory = self.memory if source == "sim" else None
         return silicon_report(self.graph, v=v, hw=hw, source=source,
                               memory=memory)
 
@@ -729,8 +612,8 @@ class StreamSession:
     with bit-identical logits.
     """
 
-    def __init__(self, deployed: DeployedProgram, batch: Optional[int] = None,
-                 backend: str = "pallas", jit: bool = True):
+    def __init__(self, deployed: PlanProgram, batch: Optional[int] = None,
+                 backend: str = "fused", jit: bool = True):
         check_backend(backend)
         self.deployed = deployed
         self.backend = backend

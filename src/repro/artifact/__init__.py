@@ -8,7 +8,7 @@ tables in one versioned, CRC-checked byte string.
 
     data   = assemble(deployed)          # DeployedProgram -> .cutie bytes
     prog   = load("net.cutie")           # -> LoadedProgram (no CutieGraph)
-    logits = prog.forward(x, backend="bitsim")   # | "ref" | "fused" | ...
+    logits = prog.forward(x)             # backend="fused" | "ref" | "bitsim"
     pool   = prog.serve(pool_size=8)     # fleet serving from the artifact
     text   = disassemble(data)           # readable listing
     assert reassemble(text) == data      # lossless round trip

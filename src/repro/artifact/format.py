@@ -234,24 +234,12 @@ def assemble_parts(info: ProgramInfo, plan, memory) -> bytes:
 
 
 def assemble(program) -> bytes:
-    """Assemble any executable program object into ``.cutie`` bytes.
-
-    Accepts a `api.program.DeployedProgram` (lowers its graph, binds its
-    packed tables — the same `WeightMemory.from_tables` path the bitsim
-    backend uses, so the images are the quantizer's bytes verbatim) or an
-    `artifact.loader.LoadedProgram` (re-assembles what was loaded; the
-    result is byte-identical to the original artifact — the loader is
-    lossless)."""
-    if hasattr(program, "info") and hasattr(program, "memory"):
-        return assemble_parts(program.info, program.plan, program.memory)
-    # DeployedProgram path
-    from repro.sim.memory import WeightMemory
-    from repro.sim.plan import lower
-
-    g = program.graph
-    plan = lower(g)
-    memory = WeightMemory.from_tables(plan, program.tables, g.act_threshold)
-    return assemble_parts(ProgramInfo.from_graph(g), plan, memory)
+    """Assemble a `DeployedProgram` or `LoadedProgram` into ``.cutie`` bytes:
+    its plan and weight-memory images (the quantizer's bytes verbatim)
+    under its metadata.  A loaded program re-assembles byte-identical to
+    the artifact it came from — the loader is lossless."""
+    return assemble_parts(ProgramInfo.from_graph(program.graph),
+                          program.plan, program.memory)
 
 
 # ---------------------------------------------------------------------------

@@ -2,133 +2,37 @@
 
 The loader reconstructs exactly what the container holds — `ProgramInfo`
 metadata, the compiled `ExecutionPlan`, and the trit-packed `WeightMemory`
-images — and wraps them in a `LoadedProgram` with the same execution surface
-as `api.program.DeployedProgram`: ``forward``/``spatial_forward``/
-``temporal_forward`` on any backend, ``stream()`` sessions, ``serve()``
-pools, and ``silicon_report()``.  There is NO `CutieGraph` (or any Python
-graph object) on this path: serving duck-types against `ProgramInfo`, and
-every backend executes the plan via `sim.execute.PlanExecutor` — the plan
-is the program, which is the whole point of shipping an artifact.
-
-Loaded programs run the trit-packed kernel datapath with plan-driven block
-shapes: the executor feeds each layer's packed image bytes straight to the
-select-decode kernels and picks ``block_cout`` per layer from the artifact's
-own `ExecutionPlan` tile geometry (`kernels.autotune`), so an artifact
-executes with the same autotuned launches as the `DeployedProgram` it was
-saved from.
+images — and wraps them in a `LoadedProgram`.  Its execution surface
+(``forward``/``spatial_forward``/``temporal_forward`` on every backend,
+``stream()`` sessions, ``serve()`` pools) is `api.program.PlanProgram`'s,
+the same code a `DeployedProgram` runs: the plan is the program.  There is
+NO `CutieGraph` (or any Python graph object) on this path: serving reads
+its metadata from `ProgramInfo`, and each layer's kernel block comes from
+the artifact's own plan geometry (`kernels.autotune`), so an artifact runs
+the same launches as the `DeployedProgram` it was saved from.
 """
 from __future__ import annotations
 
 import os
-from typing import Dict, Optional, Union
+from typing import Union
 
-import jax
-
+from repro.api.program import PlanProgram, silicon_report_from_plan
 from repro.artifact.format import ProgramInfo, assemble_parts, parse
 
 
-class LoadedProgram:
-    """An executable program reconstructed from a ``.cutie`` artifact.
-
-    Drop-in for `DeployedProgram` everywhere serving cares: `StreamSession`
-    and `SessionPool` read ``.graph`` metadata attributes and call the
-    forward/stream methods — all satisfied here from the artifact alone."""
+class LoadedProgram(PlanProgram):
+    """An executable program reconstructed from a ``.cutie`` artifact."""
 
     def __init__(self, info: ProgramInfo, plan, memory):
         self.info = info
         self.plan = plan
         self.memory = memory
-        self._executors: Dict[str, object] = {}
-
-    # -- metadata ----------------------------------------------------------
 
     @property
     def graph(self) -> ProgramInfo:
         """Serving metadata (`ProgramInfo`) under the attribute name the
-        serving stack duck-types — NOT a `CutieGraph`."""
+        serving stack reads — NOT a `CutieGraph`."""
         return self.info
-
-    @property
-    def nbytes(self) -> int:
-        """Total packed weight-image bytes (the device's weight SCM load)."""
-        return self.memory.nbytes
-
-    # -- execution ---------------------------------------------------------
-
-    def _executor(self, backend: str):
-        ex = self._executors.get(backend)
-        if ex is None:
-            from repro.sim.execute import PlanExecutor
-
-            ex = self._executors[backend] = PlanExecutor(
-                self.plan, self.memory, backend=backend
-            )
-        return ex
-
-    def spatial_forward(self, x: jax.Array, backend: str = "bitsim") -> jax.Array:
-        """Frontend (or whole spatial net): [B, H, W, C] -> features/logits."""
-        return self._executor(backend).spatial_forward(x)
-
-    def temporal_forward(self, feats: jax.Array, backend: str = "bitsim") -> jax.Array:
-        """TCN head + classifier over the ordered window [B, T, C]."""
-        return self._executor(backend).temporal_forward(feats)
-
-    def forward(self, x: jax.Array, backend: str = "bitsim") -> jax.Array:
-        """Whole-program inference, `DeployedProgram.forward` semantics:
-        spatial [B,H,W,C] -> logits; temporal frames [B,T,H,W,C] -> logits
-        over the ring window."""
-        from repro.api.program import _ring_window, check_backend
-
-        check_backend(backend)
-        if not self.info.is_temporal:
-            return self.spatial_forward(x, backend)
-        feats = jax.vmap(
-            lambda f: self.spatial_forward(f, backend), in_axes=1, out_axes=1
-        )(x)
-        return self.temporal_forward(_ring_window(feats, self.info.tcn_steps), backend)
-
-    # -- streaming / serving ----------------------------------------------
-
-    def stream_step(self, stream, frame: jax.Array, backend: str = "bitsim"):
-        """One sensor frame -> (logits, new ring) — `DeployedProgram
-        .stream_step`'s pure-functional contract over the loaded plan."""
-        from repro.api.program import check_backend
-
-        check_backend(backend)
-        feat = self.spatial_forward(frame, backend)
-        stream = stream.push(feat.astype(stream.buf.dtype))
-        window = stream.ordered()
-        if window.ndim == 2:
-            window = window[None]
-        return self.temporal_forward(window, backend), stream
-
-    def stream(self, batch: Optional[int] = None, backend: str = "bitsim",
-               jit: bool = True):
-        """A `StreamSession` over the artifact's TCN ring (temporal only)."""
-        from repro.api.program import StreamSession
-
-        if not self.info.is_temporal:
-            raise ValueError(f"{self.info.name} has no TCN memory to stream into")
-        return StreamSession(self, batch=batch, backend=backend, jit=jit)
-
-    def serve(self, pool_size: int, backend: str = "bitsim", **kwargs):
-        """A `repro.serving.SessionPool` over this loaded program — the
-        fleet path: ship one ``.cutie``, serve many sensors."""
-        from repro.serving import SessionPool
-
-        return SessionPool(self, pool_size, backend=backend, **kwargs)
-
-    def serve_fleet(self, name: Optional[str] = None, backend: str = "bitsim",
-                    **kwargs):
-        """A `repro.serving.FleetRouter` with this artifact registered
-        under ``name`` (the artifact's program name by default) — a fleet
-        tenant straight from the shipped ``.cutie``, no graph needed.
-        Register further programs on the returned router to mix tenants."""
-        from repro.serving import FleetRouter
-
-        router = FleetRouter(backend=backend, **kwargs)
-        router.register(name or self.graph.name, self)
-        return router
 
     # -- silicon model -----------------------------------------------------
 
@@ -139,8 +43,6 @@ class LoadedProgram:
         model runs on what the device would actually execute, not on an
         ideal re-derivation.  Calibration uses the paper corner carried in
         the artifact header (when present)."""
-        from repro.api.program import silicon_report_from_plan
-
         return silicon_report_from_plan(
             self.plan, v=v, hw=hw, source=source, memory=self.memory,
             paper_energy_uj=self.info.paper_energy_uj,

@@ -29,6 +29,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 TERNARY_NU_DEFAULT = 0.7  # TWN threshold factor (0.7 * E|w|)
 
@@ -152,34 +153,43 @@ def clamp_threshold(t, lo: float = 0.05, hi: float = 2.0):
 # 2-bit packing codec
 # ---------------------------------------------------------------------------
 
+def _xp(a):
+    """The codec's array module: numpy for a numpy array (host-side
+    constants, e.g. `sim.WeightMemory` images, run no device program), jax
+    for everything else."""
+    return np if isinstance(a, np.ndarray) else jnp
+
+
 def pack_ternary(t: jax.Array, axis: int = -1) -> jax.Array:
     """Pack a {-1,0,1} int array into uint8, 4 values per byte along ``axis``.
 
     The packed axis length must be a multiple of 4 (pad upstream with zeros —
     zero is a valid ternary value and contributes nothing to dot products).
     """
-    t = jnp.asarray(t)
+    xp = _xp(t)
+    t = xp.asarray(t)
     axis = axis % t.ndim
     if t.shape[axis] % 4 != 0:
         raise ValueError(f"pack axis length {t.shape[axis]} not a multiple of 4")
-    u = (t.astype(jnp.int8) + 1).astype(jnp.uint8)  # {0,1,2}
-    u = jnp.moveaxis(u, axis, -1)
+    u = (t.astype(xp.int8) + 1).astype(xp.uint8)  # {0,1,2}
+    u = xp.moveaxis(u, axis, -1)
     u = u.reshape(*u.shape[:-1], u.shape[-1] // 4, 4)
-    shifts = jnp.array([0, 2, 4, 6], dtype=jnp.uint8)
-    packed = jnp.sum(u << shifts, axis=-1).astype(jnp.uint8)
-    return jnp.moveaxis(packed, -1, axis)
+    shifts = xp.array([0, 2, 4, 6], dtype=xp.uint8)
+    packed = xp.sum(u << shifts, axis=-1).astype(xp.uint8)
+    return xp.moveaxis(packed, -1, axis)
 
 
 def unpack_ternary(p: jax.Array, axis: int = -1, *, dtype=jnp.int8) -> jax.Array:
     """Inverse of :func:`pack_ternary`; returns values in {-1,0,1}."""
-    p = jnp.asarray(p)
+    xp = _xp(p)
+    p = xp.asarray(p)
     axis = axis % p.ndim
-    p = jnp.moveaxis(p, axis, -1)
-    shifts = jnp.array([0, 2, 4, 6], dtype=jnp.uint8)
-    u = (p[..., None] >> shifts) & jnp.uint8(3)  # [..., K//4, 4]
+    p = xp.moveaxis(p, axis, -1)
+    shifts = xp.array([0, 2, 4, 6], dtype=xp.uint8)
+    u = (p[..., None] >> shifts) & xp.uint8(3)  # [..., K//4, 4]
     u = u.reshape(*u.shape[:-2], u.shape[-2] * 4)
-    t = u.astype(jnp.int8) - 1
-    return jnp.moveaxis(t.astype(dtype), -1, axis)
+    t = u.astype(xp.int8) - 1
+    return xp.moveaxis(t.astype(dtype), -1, axis)
 
 
 def select_masks(p: jax.Array, axis: int = -1) -> Tuple[jax.Array, jax.Array]:

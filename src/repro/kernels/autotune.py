@@ -87,8 +87,8 @@ def block_for_layer(lp: "LayerPlan") -> KernelBlock:
 def kernel_block_plan(plan: "ExecutionPlan") -> Dict[str, List[KernelBlock]]:
     """Per-layer blocks for every conv-kernel consumer of ``plan``, keyed
     the way the deploy tables are: ``{"conv": [...], "tcn": [...]}`` in
-    layer order.  `DeployedProgram.kernel_blocks` caches this; the
-    `PlanExecutor` derives the same values per layer directly."""
+    layer order — the blocks `sim.PlanExecutor` launches with, which it
+    derives per layer (`block_for_layer`)."""
     blocks: Dict[str, List[KernelBlock]] = {"conv": [], "tcn": []}
     for lp in plan.layers:
         if lp.kind == "conv2d":

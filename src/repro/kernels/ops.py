@@ -8,15 +8,16 @@ semantics (see ternary_conv2d.py / ternary_matmul.py):
     ops.  The default on CPU hosts: identical math to the Pallas kernel
     without paying the interpreter's per-grid-cell emulation.
   * ``impl="pallas"``  — the Pallas kernel (compiled on TPU, interpreter on
-    CPU).  The default on TPU hosts and the ``backend="pallas"`` program
-    path.
-  * ``impl="interpret"`` — the Pallas interpreter forced, any host (the
-    ``backend="interpret"`` debug path; equivalent to ``interpret=True``).
+    CPU).  The default on TPU hosts.
+  * ``impl="interpret"`` — the Pallas interpreter forced, any host: how the
+    kernel itself is tested on a CPU.
 
-``block_cout=None`` (default) lets the caller's plan decide: the deploy
-interpreter and the `PlanExecutor` thread each layer's autotuned block
-(`kernels.autotune`, from the `ExecutionPlan`'s `TileAssign` geometry) —
-the fixed 128 only remains as the fallback for plan-less direct calls.
+The deploy program's ``backend="fused"`` calls `ternary_conv2d` with no
+``impl``, so the host picks.  ``block_cout=None`` (default) lets the
+caller's plan decide: `sim.PlanExecutor` threads each layer's autotuned
+block (`kernels.autotune`, from the `ExecutionPlan`'s `TileAssign`
+geometry) — the fixed 128 only remains as the fallback for plan-less
+direct calls.
 """
 from __future__ import annotations
 
@@ -48,31 +49,21 @@ def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _resolve_impl(impl: str | None, interpret: bool | None) -> str:
-    """One resolution rule for both wrappers.  Explicit ``impl`` wins; the
-    legacy ``interpret`` bool keeps its PR-2 meaning (True -> forced
-    interpreter, False -> compiled Pallas); neither -> native on CPU,
-    compiled Pallas on TPU."""
+def _resolve_impl(impl: str | None) -> str:
+    """One resolution rule for both wrappers: an explicit ``impl`` wins;
+    None -> native on CPU, compiled Pallas on TPU."""
     if impl is not None:
         if impl not in IMPLS:
             raise ValueError(f"unknown impl {impl!r}; expected one of {IMPLS}")
         return impl
-    if interpret is True:
-        return "interpret"
-    if interpret is False:
-        return "pallas"
     return "native" if _on_cpu() else "pallas"
 
 
-def _interpret_flag(impl: str, interpret: bool | None) -> bool:
+def _interpret_flag(impl: str) -> bool:
     """The Pallas call's interpret flag once ``impl`` resolved to a Pallas
-    form: forced for impl="interpret", an explicit legacy bool is honored,
-    otherwise interpret iff the host has no Mosaic compiler (CPU)."""
-    if impl == "interpret":
-        return True
-    if interpret is not None:
-        return interpret
-    return _on_cpu()
+    form: forced for impl="interpret", otherwise interpret iff the host has
+    no Mosaic compiler (CPU)."""
+    return impl == "interpret" or _on_cpu()
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -87,7 +78,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_m", "block_n", "block_k", "interpret", "impl"),
+    static_argnames=("block_m", "block_n", "block_k", "impl"),
 )
 def ternary_matmul(
     x: jax.Array,
@@ -97,11 +88,10 @@ def ternary_matmul(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool | None = None,
     impl: str | None = None,
 ):
     """y[..., N] = x[..., K] @ select_decode(w_packed)[K, N] * scale[N]."""
-    impl = _resolve_impl(impl, interpret)
+    impl = _resolve_impl(impl)
     *lead, k = x.shape
     k4, n = w_packed.shape
     if 4 * k4 < k:
@@ -127,7 +117,7 @@ def ternary_matmul(
     bm = min(block_m, x2.shape[0])
     y = ternary_matmul_pallas(
         x2, wp, sc, block_m=bm, block_n=min(block_n, wp.shape[1]),
-        block_k=bk, interpret=_interpret_flag(impl, interpret),
+        block_k=bk, interpret=_interpret_flag(impl),
         out_dtype=x.dtype,
     )
     return y[:m, :n].reshape(*lead, n)
@@ -136,8 +126,7 @@ def ternary_matmul(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "block_cout", "fuse_ternary", "fuse_pool", "interpret", "impl",
-        "out_dtype",
+        "block_cout", "fuse_ternary", "fuse_pool", "impl", "out_dtype",
     ),
 )
 def ternary_conv2d(
@@ -150,7 +139,6 @@ def ternary_conv2d(
     threshold=0.5,
     fuse_pool: int = 0,
     residual=None,
-    interpret: bool | None = None,
     impl: str | None = None,
     out_dtype=None,
 ):
@@ -170,7 +158,7 @@ def ternary_conv2d(
     added to the scaled accumulator before the threshold (and any pool).
     On the Pallas path it launches `ternary_conv2d_residual_pallas`;
     without it the plain kernel launches with its four operands."""
-    impl = _resolve_impl(impl, interpret)
+    impl = _resolve_impl(impl)
     kh, kw, c4, c_out = w_packed.shape
     c_in = x.shape[-1]
     if 4 * c4 != c_in:
@@ -191,7 +179,7 @@ def ternary_conv2d(
     sc = _pad_to(scale.reshape(-1), 0, bc)
     th = _pad_to(thr, 0, bc)
     launch = dict(block_cout=bc, fuse_ternary=fuse_ternary, fuse_pool=fuse_pool,
-               interpret=_interpret_flag(impl, interpret),
+               interpret=_interpret_flag(impl),
                out_dtype=out_dtype or x.dtype)
     if residual is None:
         y = ternary_conv2d_pallas(x, wp, sc, th, **launch)

@@ -107,16 +107,11 @@ def layer_timeline(program, name: Optional[str] = None,
     sim counters, one span per layer with ``dur = cycles`` (1 cycle
     rendered as 1 us of virtual time), plus stall/dyn-op counter tracks.
 
-    Accepts a `DeployedProgram` or artifact `LoadedProgram` — the same
-    plan/memory duck-typing as `repro.serving.gating.frame_energy_uj`."""
+    Accepts any `api.program.PlanProgram`: a `DeployedProgram` or an
+    artifact `LoadedProgram`."""
     from repro.sim.counters import count_plan
 
-    plan = getattr(program, "plan", None)
-    if plan is None:
-        plan = program.execution_plan()
-    memory = getattr(program, "memory", None)
-    if memory is None and hasattr(program, "_bitsim"):
-        memory = program._bitsim().memory
+    plan, memory = program.plan, program.memory
     name = name or getattr(plan, "graph_name", None) or "program"
 
     counts = count_plan(plan, memory=memory)

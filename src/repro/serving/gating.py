@@ -144,12 +144,7 @@ def frame_energy_uj(program, v: float = 0.5, hw=None) -> float:
     from repro.api.program import silicon_report_from_plan
     from repro.sim.counters import evaluate_frame
 
-    plan = getattr(program, "plan", None)
-    if plan is None:
-        plan = program.execution_plan()
-    memory = getattr(program, "memory", None)
-    if memory is None and hasattr(program, "_bitsim"):
-        memory = program._bitsim().memory
+    plan, memory = program.plan, program.memory
     info = program.graph  # CutieGraph or ProgramInfo: both carry the corner
     rep = silicon_report_from_plan(
         plan, v=v, hw=hw, source="sim", memory=memory,

@@ -7,8 +7,9 @@ with an *inspectable schedule*: `lower()` compiles a `CutieGraph` into an
 weight-memory images, double-buffered feature-memory traffic, and the TCN
 ring-buffer schedule — which is then
 
-  * **executed** bit-exactly by `PlanExecutor` (the ``backend="bitsim"``
-    branch of `DeployedProgram.forward`/`stream`), and
+  * **executed** by `PlanExecutor` — the one deploy interpreter behind
+    every backend of `DeployedProgram.forward`/`stream`/`serve`, with
+    ``backend="bitsim"`` walking the tile passes themselves — and
   * **counted** by `counters.count_plan` into per-layer cycle/access numbers
     that `core.cutie_arch.evaluate_network_counts` turns into the same
     `NetReport` the analytic model produces — `silicon_report(source="sim")`.

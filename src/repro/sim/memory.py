@@ -4,9 +4,10 @@
 from a `DeployedProgram`'s tables — the exact bytes `api.quantize` packed
 (THE single pack path; no re-quantization happens here), sliced per
 `TileAssign` at execution time.  It also carries the per-OCU effective
-scales (BN folded, computed with the deploy interpreter's own formula so
-bitsim stays bit-exact) and the per-layer activation thresholds — scalar or
-per-channel vector, exactly what the fused kernel epilogue receives.
+scales (BN folded once by `api.program.effective_scale`; every backend of
+`sim.PlanExecutor` reads them) and the per-layer activation thresholds —
+scalar or per-channel vector, exactly what the fused kernel epilogue
+receives.
 
 `FeatureMemory` models the double-buffered activation memories: two banks of
 2-bit activation words; layer N reads its input map from one bank while
@@ -24,7 +25,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Union
 
-import jax
 import numpy as np
 
 from repro.api.program import effective_scale
@@ -42,7 +42,7 @@ class LayerImage:
     C_in — `api.quantize.quantize_pack_conv_weights`' layout, byte-identical
     to the deploy tables); fc [ceil(K/4), N] uint8 packed along the fan-in.
     ``eff_scale``: float32 [C_out] per-OCU scale with BN statistics folded —
-    computed with the same expression as `DeployedProgram._eff_scale`.
+    folded by `api.program.effective_scale`.
     ``threshold``: the ThFU comparator constant(s) — scalar or [C_out]."""
 
     kind: str
@@ -99,10 +99,9 @@ class LayerImage:
 
 
 def _eff_scale(entry: Dict, fan_in: int) -> np.ndarray:
-    """The deploy interpreter's own fold (`api.program.effective_scale`),
-    materialized — the constants are bitwise those of the ref/fused
-    backends because they come from the same function."""
-    return np.asarray(effective_scale(entry, fan_in), np.float32).reshape(-1)
+    """`api.program.effective_scale` as the [C_out] float32 constant every
+    backend reads."""
+    return effective_scale(entry, fan_in).reshape(-1)
 
 
 @dataclasses.dataclass
@@ -110,7 +109,7 @@ class WeightMemory:
     """All weight-layer images of one plan, in plan order (conv* tcn* fc?).
 
     ``fc_scale`` is the OPU's per-class scale, applied *after* the integer
-    trit dot (`DeployedProgram._fc`'s accumulate-then-scale order)."""
+    trit dot (`PlanExecutor._fc`'s accumulate-then-scale order)."""
 
     images: List[LayerImage]
     fc_scale: Optional[np.ndarray] = None
@@ -118,16 +117,9 @@ class WeightMemory:
     @staticmethod
     def from_tables(plan: ExecutionPlan, tables: Dict,
                     act_threshold: float) -> "WeightMemory":
-        # the images are constants of the program, never traced values —
-        # but this constructor may run lazily inside a jit trace (the
-        # executor is built on first forward), so force the folding
-        # arithmetic to evaluate at compile time
-        with jax.ensure_compile_time_eval():
-            return WeightMemory._from_tables(plan, tables, act_threshold)
-
-    @staticmethod
-    def _from_tables(plan: ExecutionPlan, tables: Dict,
-                     act_threshold: float) -> "WeightMemory":
+        """Bind the deploy tables as images.  Host-side numpy only: it may
+        run lazily inside a jit trace (a program builds its memory on first
+        forward) and then stages nothing and compiles no device program."""
         images: List[LayerImage] = []
         fc_scale = None
         ci = ti = 0

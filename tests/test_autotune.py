@@ -2,7 +2,8 @@
 
 Pins: the selection rule (plan-derived block == TileAssign width on uniform
 <=3x3 layers, measured fallback elsewhere), determinism, the deploy/executor
-threading (`DeployedProgram.kernel_blocks`, artifact-loaded execution), and
+threading (`kernel_block_plan` over `DeployedProgram.plan`, artifact-loaded
+execution), and
 the end-to-end bit-exactness of the fallback path on the 5x5-stem net the
 plan cannot schedule uniformly.
 """
@@ -108,7 +109,7 @@ class TestDeterminism:
 class TestDeployThreading:
     def test_kernel_blocks_structure(self):
         dep, _ = _deploy("dvs_cnn_tcn_smoke")
-        blocks = dep.kernel_blocks
+        blocks = kernel_block_plan(dep.plan)
         assert set(blocks) == {"conv", "tcn"}
         assert len(blocks["conv"]) == len(dep.tables["conv"])
         assert len(blocks["tcn"]) == len(dep.tables["tcn"])
@@ -119,7 +120,8 @@ class TestDeployThreading:
         """The designed fallback exerciser end-to-end: fused (autotuned
         blocks) and bitsim must stay bit-equal to the ref oracle."""
         dep, x = _deploy("cifar10_tnn_wide_smoke")
-        assert any(b.source == "fallback" for b in dep.kernel_blocks["conv"])
+        assert any(b.source == "fallback"
+                   for b in kernel_block_plan(dep.plan)["conv"])
         ref = np.asarray(dep.forward(x, backend="ref"))
         for backend in ("fused", "bitsim"):
             got = np.asarray(dep.forward(x, backend=backend))

@@ -332,7 +332,8 @@ class TestServeFleetEntryPoints:
         bitsim backend, still bit-exact vs the deployed original."""
         dep = fleet_programs["tiny_b"]
         loaded = artifact.loads(dep.to_artifact_bytes())
-        with loaded.serve_fleet(max_pool_size=2, ingest="sync") as router:
+        with loaded.serve_fleet(backend="bitsim", max_pool_size=2,
+                                ingest="sync") as router:
             bucket = router.buckets["tiny_b"]
             assert bucket.backend == "bitsim"
             clips = clips_for(dep.graph, 2, 3, seed=81)

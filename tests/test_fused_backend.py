@@ -128,8 +128,9 @@ class TestFusedProgram:
             logits = session.step(frames[:, t])
         _exact(logits, batch_fused)
 
-    def test_fused_in_backends_tuple(self):
+    @pytest.mark.parametrize("unknown", ["cuda", "pallas", "interpret"])
+    def test_fused_in_backends_tuple(self, unknown):
         assert "fused" in api.BACKENDS
         check_backend("fused")
         with pytest.raises(ValueError, match="unknown backend"):
-            check_backend("cuda")
+            check_backend(unknown)

@@ -318,7 +318,7 @@ def test_trace_diff_shapes():
 def test_layer_timeline_tracks(deployed):
     events = layer_timeline(deployed, name="tiny")
     spans = [e for e in events if e["ph"] == "X"]
-    assert len(spans) == len(deployed.execution_plan().layers)
+    assert len(spans) == len(deployed.plan.layers)
     assert all(e["dur"] >= 1 for e in spans)
     # layers tile back to back on the virtual clock
     for prev, cur in zip(spans, spans[1:]):

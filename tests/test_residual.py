@@ -157,7 +157,7 @@ def test_resnet20_smoke_bit_exact_on_every_interpreter():
     g = dep.graph
     x = _trits(np.random.RandomState(9), (6, *g.input_hw, g.input_ch)).astype(jnp.float32)
     want, maps = resnet_plain.forward(g, weights, x)
-    for be in ("fused", "ref", "bitsim", "pallas"):
+    for be in ("fused", "ref", "bitsim"):
         np.testing.assert_array_equal(np.asarray(dep.forward(x, backend=be)),
                                       np.asarray(want), err_msg=be)
     for i in g.shortcut_sources[1:] + (len(g.layers) - 3,):

@@ -407,7 +407,7 @@ class TestSimSiliconReport:
         rep = dep.silicon_report(v=0.5, source="sim")
         assert rep.source == "sim" and "sim schedule" in rep.summary()
         # the plan the report priced is the plan the bitsim backend runs
-        assert dep.execution_plan().graph_name == g.name
+        assert dep.plan.graph_name == g.name
 
 
 def test_counters_module_alias():
