@@ -25,12 +25,20 @@ Selection rule (`block_for_layer`):
 Every block is one Pallas TPU accepts: a last block dimension must be a
 multiple of the 128-lane tile or the whole array dimension (`tpu_legal`).
 
-Everything here is a pure function of the plan: same plan, same blocks —
-determinism is pinned in tests/test_autotune.py.
+The conv kernel's batch block (`batch_block`) is the other half of its grid:
+how many images one grid cell computes.  It is a pure function of the
+launch's shapes, not of the plan — the largest divisor of the batch whose
+rows (images x h x w) stay within `ROW_TARGET` and whose cell fits
+`VMEM_BUDGET`.  A batch of one, or a map already past the target, gets one
+image per cell.
+
+Everything here is a pure function of its inputs: same plan and shapes,
+same blocks — determinism is pinned in tests/test_autotune.py.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import TYPE_CHECKING, Dict, List
 
 if TYPE_CHECKING:  # avoid a hard kernels -> sim import at module load
@@ -39,6 +47,18 @@ if TYPE_CHECKING:  # avoid a hard kernels -> sim import at module load
 # The TPU vector lane count: a Pallas block's last dimension must be a
 # multiple of it, or span the whole array dimension.
 LANES = 128
+
+# Rows (images x h x w) one conv grid cell aims for: enough to amortise the
+# cell's fixed cost (weight decode, accumulator zeroing, grid step) over a
+# long matmul M, few enough that a 32x32 map still splits a batch into
+# several cells (2, 8 and 32 images a cell on 32x32, 16x16 and 8x8 maps;
+# PERF.md section 6 records the choice).
+ROW_TARGET = 2048
+
+# What one conv grid cell's blocks may hold in VMEM, below the 16 MiB that
+# v5e scopes for a kernel by default: the rest is for the decoded weights
+# and the epilogue's temporaries.
+VMEM_BUDGET = 10 * 2**20
 
 # The OCU window engine holds kh x kw <= 3 x 3 natively; anything larger
 # takes multiple window passes per tile and leaves the plan-derived regime.
@@ -96,3 +116,45 @@ def kernel_block_plan(plan: "ExecutionPlan") -> Dict[str, List[KernelBlock]]:
         elif lp.kind == "tcn":
             blocks["tcn"].append(block_for_layer(lp))
     return blocks
+
+
+def _vmem_bytes(shape, itemsize: int) -> int:
+    """Bytes an array of ``shape`` takes in VMEM, its last two dimensions
+    padded to whole (sublane, lane) tiles: 8 rows of 32 bits, 16 of 16 bits
+    or 32 of 8 bits, by 128 lanes."""
+    *lead, rows, lanes = shape
+    sub = 8 * (4 // itemsize)
+    return (math.prod(lead) * itemsize * -(-rows // sub) * sub
+            * -(-lanes // LANES) * LANES)
+
+
+def cell_vmem_bytes(bb: int, h: int, w: int, c_in: int, block_cout: int, *,
+                    kh: int = 3, kw: int = 3, in_bytes: int = 1,
+                    out_bytes: int = 1, res_bytes: int = 1) -> int:
+    """One conv grid cell of ``bb`` images: its double-buffered input,
+    output and residual blocks (the output taken at the unpooled size; no
+    residual where ``res_bytes`` is 0), the f32 accumulator and one tap
+    window widened to f32."""
+    hp, wp = h + kh - 1, w + kw - 1
+    out = _vmem_bytes((bb, h, w, block_cout), out_bytes)
+    res = _vmem_bytes((bb, h, w, block_cout), res_bytes) if res_bytes else 0
+    return (2 * (_vmem_bytes((bb, hp, wp, c_in), in_bytes) + out + res)
+            + _vmem_bytes((bb * h * w, block_cout), 4)
+            + _vmem_bytes((bb, h, w, c_in), 4))
+
+
+def batch_block(b: int, h: int, w: int, c_in: int, block_cout: int,
+                **sizes) -> int:
+    """Images per conv grid cell: the largest divisor ``bb`` of the batch
+    ``b`` with ``bb * h * w <= ROW_TARGET`` and the cell's blocks within
+    `VMEM_BUDGET` (`cell_vmem_bytes`, which takes ``sizes``: window and
+    item sizes); 1 where no larger divisor fits."""
+    best = 1
+    for bb in range(2, b + 1):
+        if b % bb:
+            continue
+        if (bb * h * w > ROW_TARGET
+                or cell_vmem_bytes(bb, h, w, c_in, block_cout, **sizes) > VMEM_BUDGET):
+            break
+        best = bb
+    return best

@@ -2,12 +2,15 @@
 
 CUTIE's datapath: a line buffer holds a 3-row window of the (SAME-padded)
 input feature map; every cycle, all 96 OCUs consume the full 3x3xC_in window
-of one output pixel.  The translation here keeps the *whole padded image* of
-one sample resident (CUTIE's maximum 64x64x96 map is ~0.8 MB in bf16 —
-comfortably VMEM-sized; that is exactly why the silicon could afford
-all-on-chip feature maps, and the same dimensioning argument holds here),
-and expresses the window reuse as 9 shifted [H*W, C_in] x [C_in, bn]
-matmuls accumulated output-stationary.
+of one output pixel.  The translation here keeps *whole padded images*
+resident (CUTIE's maximum 64x64x96 map is ~0.8 MB in bf16 — comfortably
+VMEM-sized; that is exactly why the silicon could afford all-on-chip
+feature maps, and the same dimensioning argument holds here), and expresses
+the window reuse as 9 shifted [bb*H*W, C_in] x [C_in, bn] matmuls
+accumulated output-stationary.  One grid cell holds a block of ``bb``
+images (`kernels.autotune.batch_block`, a pure function of the launch's
+shapes), so the weight decode and the cell's fixed cost are paid once per
+block; a batch of one runs one image per cell.
 
 Weights arrive 2-bit packed along C_in: [KH, KW, C_in/4, C_out] uint8 — the
 quantizer's deploy-table bytes, consumed **verbatim**.  The in-register
@@ -54,6 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.autotune import batch_block
 from repro.kernels.ternary_matmul import select_tile
 
 
@@ -83,25 +87,28 @@ def _tconv_kernel(
     x_ref, wp_ref, scale_ref, thr_ref, *refs, h: int, w: int,
     kh: int, kw: int, fuse_ternary: bool, fuse_pool: int, residual: bool,
 ):
-    """One (sample, output-channel-tile) grid cell: full-image conv.
+    """One (image-block, output-channel-tile) grid cell: the full-image conv
+    of each of its ``bb`` images, against one decode of the weights.
     ``refs`` is ``(res_ref, o_ref, acc_ref)`` with a residual operand,
     ``(o_ref, acc_ref)`` without."""
     o_ref, acc_ref = refs[-2:]
-    c_in = x_ref.shape[-1]
+    bb, c_in = x_ref.shape[0], x_ref.shape[-1]
     bn = o_ref.shape[-1]
     # [KH, KW, C4, bn] uint8 -> [KH, KW, C_in, bn]: the packed C_in axis is
     # axis -2, exactly the matmul kernel's K axis
     wt = select_tile(wp_ref[...], jnp.float32)
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    # 9 shifted matmuls == the line-buffer window walk, output-stationary.
-    # The window is widened to f32 before the (h, w, c) -> (h*w, c) merge:
-    # Mosaic cannot merge int8 rows at every width (TCN D=2 has w=2).
+    # 9 shifted matmuls == the line-buffer window walk, output-stationary,
+    # the block's images riding as extra rows of M (image-major, as the
+    # native path folds its batch).  The window is widened to f32 before
+    # the (bb, h, w, c) -> (bb*h*w, c) merge: Mosaic cannot merge int8 rows
+    # at every width (TCN D=2 has w=2).
     for dy in range(kh):
         for dx in range(kw):
-            xs = x_ref[0, dy : dy + h, dx : dx + w, :].astype(jnp.float32)
+            xs = x_ref[:, dy : dy + h, dx : dx + w, :].astype(jnp.float32)
             acc_ref[...] += jax.lax.dot_general(
-                xs.reshape(h * w, c_in),
+                xs.reshape(bb * h * w, c_in),
                 wt[dy, dx],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -110,12 +117,12 @@ def _tconv_kernel(
     res = None
     if residual:
         # widened before the merge, as the window is
-        res = refs[0][0].astype(jnp.float32).reshape(h * w, bn)
+        res = refs[0][...].astype(jnp.float32).reshape(bb * h * w, bn)
     y = _epilogue(
-        acc_ref[...], scale_ref[...], thr_ref[...], h=h, w=w, bn=bn,
+        acc_ref[...], scale_ref[...], thr_ref[...], h=bb * h, w=w, bn=bn,
         fuse_ternary=fuse_ternary, fuse_pool=fuse_pool, res=res,
     )
-    o_ref[...] = y[None].astype(o_ref.dtype)
+    o_ref[...] = y.reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def _check_residual(residual, b, h, w, c_out):
@@ -158,6 +165,10 @@ def _conv2d_pallas(x, w_packed, scale, threshold, residual, *, block_cout,
     scale = scale.reshape(1, c_out)
     thr = threshold.reshape(1, c_out)
     oh, ow = (h // fuse_pool, w // fuse_pool) if fuse_pool > 1 else (h, w)
+    bb = batch_block(
+        b, h, w, c_in, block_cout, kh=kh, kw=kw, in_bytes=x.dtype.itemsize,
+        out_bytes=jnp.dtype(out_dtype).itemsize,
+        res_bytes=0 if residual is None else residual.dtype.itemsize)
 
     kern = functools.partial(
         _tconv_kernel, h=h, w=w, kh=kh, kw=kw,
@@ -165,7 +176,7 @@ def _conv2d_pallas(x, w_packed, scale, threshold, residual, *, block_cout,
         residual=residual is not None,
     )
     in_specs = [
-        pl.BlockSpec((1, h + kh - 1, w + kw - 1, c_in), lambda i, j: (i, 0, 0, 0)),
+        pl.BlockSpec((bb, h + kh - 1, w + kw - 1, c_in), lambda i, j: (i, 0, 0, 0)),
         pl.BlockSpec((kh, kw, c4, block_cout), lambda i, j: (0, 0, 0, j)),
         pl.BlockSpec((1, block_cout), lambda i, j: (0, j)),
         pl.BlockSpec((1, block_cout), lambda i, j: (0, j)),
@@ -174,15 +185,15 @@ def _conv2d_pallas(x, w_packed, scale, threshold, residual, *, block_cout,
     if residual is not None:
         _check_residual(residual, b, h, w, c_out)
         in_specs.append(
-            pl.BlockSpec((1, h, w, block_cout), lambda i, j: (i, 0, 0, j)))
+            pl.BlockSpec((bb, h, w, block_cout), lambda i, j: (i, 0, 0, j)))
         operands.append(residual)
     return pl.pallas_call(
         kern,
-        grid=(b, c_out // block_cout),
+        grid=(b // bb, c_out // block_cout),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, oh, ow, block_cout), lambda i, j: (i, 0, 0, j)),
+        out_specs=pl.BlockSpec((bb, oh, ow, block_cout), lambda i, j: (i, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, c_out), out_dtype),
-        scratch_shapes=[pltpu.VMEM((h * w, block_cout), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bb * h * w, block_cout), jnp.float32)],
         interpret=interpret,
         name=name,
     )(*operands)
@@ -265,7 +276,7 @@ def ternary_conv2d_native(
     """The Pallas kernel's exact tap walk as straight XLA ops — same select
     decode, same 9 shifted matmuls in the same order, same `_epilogue` —
     with the batch folded into the matmul M dimension (one [B*H*W, C_in] x
-    [C_in, C_out] dot per tap instead of one grid cell per sample).  This is
+    [C_in, C_out] dot per tap instead of one grid cell per image block).  This is
     the CPU-native packed path `ops.ternary_conv2d` dispatches when no
     Pallas machinery is requested; there is no block tiling because XLA
     tiles the dots itself.  ``residual`` [B, H, W, C_out] is the shortcut,

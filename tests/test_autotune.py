@@ -5,7 +5,9 @@ Pins: the selection rule (plan-derived block == TileAssign width on uniform
 threading (`kernel_block_plan` over `DeployedProgram.plan`, artifact-loaded
 execution), and
 the end-to-end bit-exactness of the fallback path on the 5x5-stem net the
-plan cannot schedule uniformly.
+plan cannot schedule uniformly.  The conv kernel's batch block
+(`batch_block`): its bounds, and its picks in every conv launch of the
+benchmarked steps.
 """
 import jax
 import jax.numpy as jnp
@@ -14,8 +16,12 @@ import pytest
 
 from repro import api
 from repro.kernels.autotune import (
+    ROW_TARGET,
+    VMEM_BUDGET,
     KernelBlock,
+    batch_block,
     block_for_layer,
+    cell_vmem_bytes,
     kernel_block_plan,
     tpu_legal,
 )
@@ -139,3 +145,101 @@ class TestDeployThreading:
         ref = np.asarray(dep.forward(x, backend="ref"))
         got = np.asarray(loaded.forward(x, backend="fused"))
         np.testing.assert_array_equal(got.astype(ref.dtype), ref)
+
+
+# (B, h, w, c_in, block_cout, input bytes): every conv map the registry nets
+# launch, at batches with few and with many divisors
+BATCH_SHAPES = [
+    (b, h, w, c, n, ib)
+    for b in (1, 3, 8, 12, 64, 256)
+    for (h, w, c, n, ib) in [
+        (32, 32, 4, 96, 4), (32, 32, 96, 96, 1), (16, 16, 96, 96, 1),
+        (8, 8, 96, 96, 1), (8, 8, 64, 64, 1), (64, 64, 4, 64, 4),
+        (4, 4, 96, 96, 1), (25, 1, 96, 96, 1), (13, 2, 96, 96, 1),
+        (7, 4, 96, 96, 1), (4, 8, 96, 96, 1),
+    ]
+]
+
+
+def _conv_launches(jaxpr):
+    """Per `pallas_call` in ``jaxpr`` (nested jaxprs included), in launch
+    order: (input shape, images per grid cell)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            shape = eqn.invars[0].aval.shape
+            out.append((shape, shape[0] // eqn.params["grid_mapping"].grid[0]))
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                if isinstance(sub, ClosedJaxpr):
+                    out += _conv_launches(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    out += _conv_launches(sub)
+    return out
+
+
+def _step_launches(name, batch, monkeypatch):
+    """The conv launches of the step a benchmark cell times, traced for the
+    chip's Pallas path: a one-shot net's fused forward over ``batch``
+    images, or a DVS pool's step over ``batch`` slots."""
+    import repro.kernels.ops as ops
+    from repro.serving import SessionPool
+
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    prog = api.get_net(name)
+    dep = prog.quantize(prog.init(jax.random.PRNGKey(0)))
+    g = dep.graph
+    if g.is_temporal:
+        pool = SessionPool(dep, batch, backend="fused")
+        jx = jax.make_jaxpr(pool._step)(
+            pool.state, jax.ShapeDtypeStruct((batch, *pool.frame_shape), jnp.float32),
+            jnp.zeros((batch,), jnp.int8))
+    else:
+        jx = jax.make_jaxpr(lambda x: dep.forward(x, backend="fused"))(
+            jax.ShapeDtypeStruct((batch, *g.input_hw, g.input_ch), jnp.float32))
+    return _conv_launches(jx.jaxpr)
+
+
+class TestBatchBlock:
+    @pytest.mark.parametrize("b,h,w,c_in,block,in_bytes", BATCH_SHAPES)
+    def test_divides_and_stays_within_bounds(self, b, h, w, c_in, block, in_bytes):
+        bb = batch_block(b, h, w, c_in, block, in_bytes=in_bytes)
+        assert b % bb == 0
+        if bb > 1:
+            assert bb * h * w <= ROW_TARGET
+            assert cell_vmem_bytes(bb, h, w, c_in, block,
+                                   in_bytes=in_bytes) <= VMEM_BUDGET
+        # the largest such divisor: the next one up breaks a bound
+        nxt = next((d for d in range(bb + 1, b + 1) if b % d == 0), None)
+        if nxt is not None:
+            assert (nxt * h * w > ROW_TARGET
+                    or cell_vmem_bytes(nxt, h, w, c_in, block,
+                                       in_bytes=in_bytes) > VMEM_BUDGET)
+
+    def test_one_image_per_cell_for_one_image_and_for_the_64x64_stem(self):
+        for shape in [(32, 32, 96, 96), (8, 8, 96, 96), (25, 1, 96, 96)]:
+            assert batch_block(1, *shape) == 1
+        for b in (8, 64, 256):
+            assert batch_block(b, 64, 64, 4, 64, in_bytes=4) == 1
+
+    def test_deterministic(self):
+        picks = [batch_block(*s[:5], in_bytes=s[5]) for s in BATCH_SHAPES]
+        assert picks == [batch_block(*s[:5], in_bytes=s[5]) for s in BATCH_SHAPES]
+
+    # images per grid cell of every conv launch, in launch order, in the
+    # steps the benchmark's cells time (cifar_b256, resnet20_b256,
+    # dvs_pool8 / dvs_pool32_x4 per chip, dvs_pool64)
+    PICKS = {
+        ("cifar10_tnn", 256): [2, 2, 8, 8, 8, 32, 32, 32],
+        ("resnet20_tnn", 256): [2] * 8 + [8] * 6 + [32] * 5,
+        ("dvs_cnn_tcn", 8): [1, 2, 8, 8, 8, 8, 8, 8, 8],
+        ("dvs_cnn_tcn", 64): [1, 2, 8, 32, 64, 16, 32, 32, 64],
+    }
+
+    @pytest.mark.parametrize("name,batch", list(PICKS))
+    def test_picks_in_the_benchmarked_steps(self, name, batch, monkeypatch):
+        launches = _step_launches(name, batch, monkeypatch)
+        assert [bb for _, bb in launches] == self.PICKS[name, batch]
+        assert all(shape[0] == batch for shape, _ in launches)

@@ -300,3 +300,43 @@ class TestTernaryConv2dKernel:
             y2d = ternary_conv2d(zp, wp, sc)[:, : z.shape[1], :, :]
             got = unwrap_time_axis(y2d, 24)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(y_ref))
+
+
+# (batch, map, fuse_pool, residual, C_out, block_cout) for the image-blocked
+# conv grid: a prime batch forces one image per cell, 12 has several
+# divisors; the maps are CIFAR's and the TCN's dilation wraps (25,1) ..
+# (4,8); the last case has a ragged C_out over two channel blocks.
+_MAPS = [(32, 32), (16, 16), (8, 8), (25, 1), (13, 2), (7, 4), (4, 8)]
+BLOCKED_CASES = [
+    (b, hw, pool, res, 16, 16)
+    for b in (1, 3, 8, 12)
+    for hw in _MAPS
+    for pool in ((0, 2) if hw[0] % 2 == 0 and hw[1] % 2 == 0 else (0,))
+    for res in (False, True)
+] + [(12, (16, 16), 2, True, 20, 16)]
+
+
+class TestImageBlockedConv:
+    """The Pallas conv grid computes a block of images per cell
+    (`kernels.autotune.batch_block`); interpreted, it must equal the
+    batch-folded native path bit for bit."""
+
+    @pytest.mark.parametrize("b,hw,pool,res,c_out,block", BLOCKED_CASES)
+    def test_blocked_kernel_equals_native_bit_exact(self, b, hw, pool, res, c_out,
+                                                    block):
+        rng = np.random.RandomState(b * 1000 + hw[0] * 10 + hw[1])
+        h, w = hw
+        c_in = 8
+        x = jnp.asarray(rng.randint(-1, 2, (b, h, w, c_in)).astype(np.int8))
+        t = jnp.asarray(rng.randint(-1, 2, (3, 3, c_in, c_out)).astype(np.int8))
+        wp = pack_ternary(t, axis=2)
+        sc = jnp.asarray(rng.choice([0.25, 0.5, 1.0], c_out).astype(np.float32))
+        shortcut = (jnp.asarray(rng.randint(-1, 2, (b, h, w, c_out)).astype(np.int8))
+                    if res else None)
+        kw = dict(fuse_ternary=True, threshold=0.6, fuse_pool=pool,
+                  residual=shortcut, out_dtype=jnp.int8)
+        y_nat = ternary_conv2d(x, wp, sc, impl="native", **kw)
+        y_int = ternary_conv2d(x, wp, sc, block_cout=block, impl="interpret", **kw)
+        p = pool or 1
+        assert y_int.shape == (b, h // p, w // p, c_out)
+        np.testing.assert_array_equal(np.asarray(y_nat), np.asarray(y_int))

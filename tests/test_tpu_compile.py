@@ -82,6 +82,18 @@ CONV_CASES = {
     "tcn_d2": ((2, 13, 2, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
     "tcn_d4": ((2, 7, 4, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
     "tcn_d8": ((2, 4, 8, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
+    # at the cells' batches, so the grid cells hold the image blocks
+    # `kernels.autotune.batch_block` picks there: 2, 8 and 32 images of 256,
+    # and 16, 32, 32, 64 TCN windows of 64
+    "cifar_96_pooled_b256": ((256, 32, 32, 96), (3, 3, 24, 96), 2, 96, jnp.int8),
+    "cifar_16x16_b256": ((256, 16, 16, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
+    "cifar_16x16_pooled_b256": ((256, 16, 16, 96), (3, 3, 24, 96), 2, 96, jnp.int8),
+    "cifar_8x8_b256": ((256, 8, 8, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
+    "cifar_8x8_pooled_b256": ((256, 8, 8, 96), (3, 3, 24, 96), 2, 96, jnp.int8),
+    "tcn_d1_b64": ((64, 25, 1, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
+    "tcn_d2_b64": ((64, 13, 2, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
+    "tcn_d4_b64": ((64, 7, 4, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
+    "tcn_d8_b64": ((64, 4, 8, 96), (3, 3, 24, 96), 0, 96, jnp.int8),
 }
 
 
@@ -92,20 +104,25 @@ def test_conv_kernel_compiles_for_v5e(one_chip, case):
     assert "tpu_custom_call" in hlo
 
 
-# resnet20_tnn's shortcut convs: (H = W, C) of each stage
-RESIDUAL_CASES = {"32x32x16": (32, 16), "16x16x32": (16, 32), "8x8x64": (8, 64)}
+# resnet20_tnn's shortcut convs: (batch, H = W, C) of each stage, at two
+# images and at resnet20_b256's batch (2, 8 and 32 images per grid cell)
+RESIDUAL_CASES = {
+    "32x32x16": (2, 32, 16), "16x16x32": (2, 16, 32), "8x8x64": (2, 8, 64),
+    "32x32x16_b256": (256, 32, 16), "16x16x32_b256": (256, 16, 32),
+    "8x8x64_b256": (256, 8, 64),
+}
 
 
 @pytest.mark.parametrize("case", list(RESIDUAL_CASES))
 def test_residual_conv_kernel_compiles_for_v5e(one_chip, case):
     """The residual epilogue (int8 shortcut operand, blocked like the
     output) at each ResNet-20 stage, under its own launch name."""
-    hw, c = RESIDUAL_CASES[case]
+    b, hw, c = RESIDUAL_CASES[case]
     lowered = ternary_conv2d_residual_pallas.lower(
-        _spec((2, hw, hw, c), jnp.int8, one_chip),
+        _spec((b, hw, hw, c), jnp.int8, one_chip),
         _spec((3, 3, c // 4, c), jnp.uint8, one_chip),
         _spec((c,), jnp.float32, one_chip), _spec((c,), jnp.float32, one_chip),
-        _spec((2, hw, hw, c), jnp.int8, one_chip),
+        _spec((b, hw, hw, c), jnp.int8, one_chip),
         block_cout=c, fuse_ternary=True, interpret=False, out_dtype=jnp.int8,
     )
     hlo = lowered.compile().as_text()
